@@ -1,7 +1,7 @@
 """End-to-end kernel flow through the operator registry: tune a
 workload per op, persist the records, and execute the real Pallas
-kernels (interpret mode on CPU) with the tuned schedules, validated
-against their oracles.
+kernels (interpret mode on CPU, compiled on a TPU) with the tuned
+schedules, validated against their oracles.
 
 The op registry (`repro.core.ops`) is the only place that knows what a
 "gemm" or a "flash" is — the tuner invocation below is identical for
@@ -34,6 +34,7 @@ from repro.core import (
     workload_key_for,
 )
 from repro.core.tuners import GBFSTuner
+from repro.kernels import interpret_default
 from repro.kernels import ops as kernel_ops
 from repro.kernels.ref import ref_gemm
 
@@ -62,9 +63,8 @@ def main():
     )
     set_global_records(records)
 
-    kernel_ops.set_kernel_policy(
-        kernel_ops.KernelPolicy(use_pallas=True, interpret=True)
-    )
+    # interpreted on the CPU, compiled natively on a TPU
+    kernel_ops.set_kernel_policy(kernel_ops.KernelPolicy(use_pallas=True))
     rng = np.random.default_rng(0)
     a = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
     b = jnp.asarray(rng.standard_normal((k, n)), jnp.float32)
@@ -89,7 +89,7 @@ def main():
     flash = get_op("flash")
     operands = flash.timed_operands(fspace, "float32", seed=0)
     tuned_out = flash.pallas_run(fspace, fres.best_state, operands,
-                                 interpret=True)
+                                 interpret=interpret_default())
     import jax
 
     q, kk, v = operands

@@ -59,6 +59,10 @@ class CostBackend(abc.ABC):
     """Measures ``cost(s; m, k, n, d_m, d_k, d_n)`` (paper Sec. 3.3)."""
 
     name: str = "base"
+    #: True for backends that run the schedule's program on JAX's device
+    #: and time it: their cost grows with the schedule's grid, and only
+    #: the process that holds the device can measure them
+    measured: bool = False
 
     def __init__(self, space: SearchSpace, n_repeats: int = 1):
         self.space = space
@@ -152,6 +156,7 @@ class CountingCost(CostBackend):
         super().__init__(inner.space, n_repeats=1)
         self.inner = inner
         self.name = f"counting({inner.name})"
+        self.measured = inner.measured
         self.n_measured = 0
         self.simulated_clock_s = 0.0
         self.wall_started = time.monotonic()
@@ -241,6 +246,7 @@ class SleepingCost(CostBackend):
         super().__init__(inner.space, n_repeats=1)
         self.inner = inner
         self.name = f"sleeping({inner.name})"
+        self.measured = inner.measured
         self.delay_s = delay_s
         self.hang_s = hang_s
         self.raise_keys = frozenset(raise_keys)

@@ -1,15 +1,17 @@
 """Measured cost backends — real wall-clock oracles.
 
 The paper measures candidate configurations on real hardware (Titan Xp).
-These backends do the honest equivalent available in this container:
+These backends time programs on JAX's default device:
 
 * :class:`XLATimedCost` — realizes the *blocked loop structure* of a
-  schedule as an XLA:CPU program and times it.  The per-op build recipe
+  schedule as an XLA program and times it on the device (the CPU in the
+  tests, the TPU on a chip host; the device kind is part of its records
+  namespace, fingerprint and cache keys).  The per-op build recipe
   comes from the op registry (``repro.core.ops``): a tiled macro-grid
   matmul for ``gemm``, the blocked online-softmax loop for ``flash``.
-  Different schedules genuinely run at different speeds on the CPU cache
-  hierarchy, so the search problem is real, just on a different memory
-  system than the TPU target.
+  Different schedules genuinely run at different speeds, so the search
+  problem is real; the program is an emulation of the kernel's
+  schedule, not the Pallas kernel itself.
 
   Compilation — not timing, not search logic — dominates the trial cost
   of this backend, so it is engineered out of the hot path at every
@@ -39,8 +41,8 @@ These backends do the honest equivalent available in this container:
   (via the registry's ``pallas_run`` binding) in ``interpret=True``
   mode.  Functionally faithful to the TPU kernel; timing reflects the
   interpreter, so this backend is for correctness-coupled search demos
-  on small shapes.  Process-shippable via ``worker_spec()`` like the
-  other backends.
+  on small shapes, and is refused on a TPU backend.  Process-shippable
+  via ``worker_spec()`` like the other backends.
 
 Both are deliberately interchangeable with :class:`AnalyticalTPUCost`
 behind the same :class:`CostBackend` protocol (DESIGN.md §2).
@@ -58,6 +60,8 @@ import time
 from collections import OrderedDict
 from typing import Optional
 
+
+from repro.utils.device import device_kind
 
 from ..space import SearchSpace, State
 from .base import CostBackend
@@ -144,8 +148,9 @@ class ExecutableCache:
         space: SearchSpace, dtype: str, state: State, flavor: str = ""
     ) -> str:
         """Content key: the compiled program is fully determined by the
-        op, its workload dims, dtype, schedule state, and the jax/jaxlib
-        (XLA) version that produced it.  The op field keeps one shared
+        op, its workload dims, dtype, schedule state, the device kind it
+        was compiled for, and the jax/jaxlib (XLA) version that produced
+        it.  The op field keeps one shared
         cache directory safe across operators; ``flavor`` separates
         program families that would otherwise collide on the same
         (op, dims, state) — e.g. the interpret-mode Pallas program and
@@ -164,7 +169,7 @@ class ExecutableCache:
         extra = "".join(f"/{k}={v!r}" for k, v in sorted(kw.items()))
         fl = f"/{flavor}" if flavor else ""
         raw = (
-            f"{op}/{dims}/{dtype}/{state.key()}{extra}{fl}"
+            f"{op}/{dims}/{dtype}/{state.key()}{extra}{fl}/{device_kind()}"
             f"/jax{jax.__version__}/jaxlib{jaxlib.__version__}"
         )
         return hashlib.sha256(raw.encode()).hexdigest()[:40]
@@ -278,7 +283,11 @@ def _xla_timed_from_spec(
 
 
 class XLATimedCost(CostBackend):
-    name = "xla_cpu_timed"
+    """Times the op's XLA realization of a schedule on JAX's device; its
+    ``name`` (the records namespace) carries the device kind:
+    ``xla_cpu_timed`` on the CPU, ``xla_tpu_v5_lite_timed`` on a v5e."""
+
+    measured = True
 
     def __init__(
         self,
@@ -299,6 +308,8 @@ class XLATimedCost(CostBackend):
         from ..ops import get_op  # lazy: the registry imports cost modules
 
         self._jax, self._jnp = jax, jnp
+        self.device_kind = device_kind()
+        self.name = f"xla_{self.device_kind.lower().replace(' ', '_')}_timed"
         self.dtype = dtype
         self.vmem_guard_bytes = vmem_guard_bytes
         self.seed = seed
@@ -442,10 +453,11 @@ class XLATimedCost(CostBackend):
 
     # -- CostBackend protocol ------------------------------------------------
     def measure_fingerprint(self) -> str:
-        # seed fixes the operand contents; dtype changes the program
+        # seed fixes the operand contents; dtype changes the program;
+        # the device kind says what the seconds were measured on
         return (
             f"r{self.n_repeats}|{self.dtype}|seed{self.seed}"
-            + self.space_fingerprint()
+            f"|{self.device_kind}" + self.space_fingerprint()
         )
 
     def compile_stats(self) -> Optional[dict]:
@@ -523,6 +535,7 @@ class PallasInterpretCost(CostBackend):
     XLATimedCost programs of the same schedule without collision."""
 
     name = "pallas_interpret_timed"
+    measured = True
     _FLAVOR = "pallas_interpret"
 
     def __init__(
@@ -536,8 +549,11 @@ class PallasInterpretCost(CostBackend):
         super().__init__(space, n_repeats)
         import jax
 
+        from repro.kernels import check_interpret
+
         from ..ops import get_op  # lazy: the registry imports cost modules
 
+        check_interpret(True)  # timing the interpreter on a TPU is refused
         self._jax = jax
         self.seed = seed
         self._opspec = get_op(self.op)

@@ -33,7 +33,10 @@ measurement (an XLA compile easily outlives a 4 s charging cap).
   raises reports the error and lives on; a worker that dies (segfault,
   ``os._exit``, OOM-kill) or blows the per-lane timeout is reaped and
   respawned, and its lane resolves to ``inf`` — a backend crash can no
-  longer take down the tuning session.
+  longer take down the tuning session.  Workers run JAX on the CPU: a
+  chip belongs to one process, and the parent may hold it, so a backend
+  that measures on the device is refused on process lanes when the
+  parent's backend is a TPU.
 
 Executors with ``real_time = True`` report *measured* per-lane wall
 seconds; the engine charges those to the search clock instead of the
@@ -47,6 +50,8 @@ import abc
 import dataclasses
 import math
 import multiprocessing
+import os
+import sys
 import time
 from typing import Optional, Sequence
 
@@ -208,7 +213,13 @@ def _worker_main(conn) -> None:
     or ``("err", message)``.  ``compile_delta`` is the job's increment of
     ``backend.compile_stats()`` (None for backends without a build step)
     so the engine can attribute compile-cache hits across the process
-    boundary.  Runs until the sentinel ``None`` or parent death."""
+    boundary.  Runs until the sentinel ``None`` or parent death.
+
+    JAX runs on the CPU here: a worker that tried the TPU would find it
+    held by the parent, and fall back to the CPU without a word."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:  # imported with the start method's preload
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
     backends: dict = {}
     while True:
         try:
@@ -427,9 +438,26 @@ class ProcessExecutor(LaneExecutor):
             except (EOFError, OSError):
                 pass  # dead at birth: run_wave resolves its lane to inf
 
+    @staticmethod
+    def _refuse_device_backend(backend) -> None:
+        """Workers run JAX on the CPU, so a backend that times programs
+        on JAX's device would time the CPU there while this process holds
+        the TPU."""
+        if getattr(backend, "measured", False):
+            import jax
+
+            if jax.default_backend() == "tpu":
+                raise ValueError(
+                    f"backend {backend.name!r} times programs on the JAX "
+                    "device, and this process holds the TPU: process lanes "
+                    "run JAX on the CPU (one process per chip), so they "
+                    "would time the CPU; use the sim or thread executor"
+                )
+
     def run_wave(self, backend, states, timeout_s=None):
         import threading
 
+        self._refuse_device_backend(backend)
         spec = backend.worker_spec()
         if spec is None:
             raise ValueError(
@@ -562,6 +590,8 @@ class ProcessExecutor(LaneExecutor):
         of inside the first measurement wave.  Spawning more lanes than
         the wave width parks warm spares that dead lanes adopt instantly
         (see ``_ensure_workers``)."""
+        if backend is not None:
+            self._refuse_device_backend(backend)
         self._ensure_workers(n_lanes)
         if backend is None:
             return

@@ -213,6 +213,7 @@ class FaultInjectionCost(CostBackend):
         self.fault_dir = fault_dir
         self.delay_s = delay_s
         self.name = f"faulty({inner.name})"
+        self.measured = inner.measured
 
     def cost_once(self, s: State, repeat_idx: int) -> float:  # pragma: no cover
         raise RuntimeError("FaultInjectionCost delegates via cost()")

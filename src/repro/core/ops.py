@@ -10,6 +10,8 @@ stack.  An :class:`OpSpec` names:
   a schedule as a *timed XLA:CPU program* (operands + traceable fn)
 * ``pallas_run``       — how :class:`PallasInterpretCost` executes the
   op's real Pallas kernel under a schedule (interpret mode on CPU)
+* ``default_blocks``   — the kernel's heuristic blocks for a workload,
+  where a measured search starts (:func:`heuristic_state`)
 
 Everything downstream (tuners, the measurement engine, journals,
 ``TuningSession``, the tune CLI) resolves ops through :func:`get_op` and
@@ -35,7 +37,7 @@ from .config_space import GemmConfigSpace, TilingState
 from .flash_space import FlashAttnConfigSpace, FlashScheduleState
 from .space import SearchSpace
 
-__all__ = ["OpSpec", "OPS", "register_op", "get_op", "op_names"]
+__all__ = ["OpSpec", "OPS", "register_op", "get_op", "op_names", "heuristic_state"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +58,9 @@ class OpSpec:
     #: (space, state, operands, interpret) -> output array via the real
     #: Pallas kernel, or None when the op has no kernel binding
     pallas_run: Optional[Callable] = None
+    #: dims -> the kernel's heuristic block size per factored dim (where
+    #: dispatch runs without a record), or None
+    default_blocks: Optional[Callable[[tuple], tuple[int, ...]]] = None
 
 
 OPS: dict[str, OpSpec] = {}
@@ -76,6 +81,15 @@ def get_op(name: str) -> OpSpec:
 
 def op_names() -> list[str]:
     return sorted(OPS)
+
+
+def heuristic_state(space: SearchSpace):
+    """The schedule the op's kernel runs without a tuning record, as a
+    state of ``space``; None when the op names no heuristic."""
+    spec = get_op(space.op)
+    if spec.default_blocks is None:
+        return None
+    return space.blocked_state(spec.default_blocks(space.dims))
 
 
 # ---------------------------------------------------------------------------
@@ -135,12 +149,19 @@ def _gemm_timed_fn(space: GemmConfigSpace, s: TilingState, dtype: str) -> Callab
     return fn
 
 
-def _gemm_pallas_run(space: GemmConfigSpace, s: TilingState, operands, interpret=True):
+def _gemm_pallas_run(space: GemmConfigSpace, s: TilingState, operands, interpret: bool):
     from repro.kernels.gemm import gemm_pallas, kernel_config_from_state
 
     cfg = kernel_config_from_state(s)  # ValueError -> inf at the caller
     A, B = operands
     return gemm_pallas(A, B, cfg, interpret=interpret)
+
+
+def _gemm_default_blocks(dims) -> tuple[int, int, int]:
+    from repro.kernels.gemm import default_config
+
+    cfg = default_config(*dims)
+    return cfg.block_m, cfg.block_k, cfg.block_n
 
 
 # ---------------------------------------------------------------------------
@@ -231,7 +252,7 @@ def _flash_timed_fn(
 
 
 def _flash_pallas_run(
-    space: FlashAttnConfigSpace, s: FlashScheduleState, operands, interpret=True
+    space: FlashAttnConfigSpace, s: FlashScheduleState, operands, interpret: bool
 ):
     from repro.kernels.flash_attention import flash_attention
 
@@ -248,6 +269,13 @@ def _flash_pallas_run(
     )
 
 
+def _flash_default_blocks(dims) -> tuple[int, int]:
+    from repro.kernels.flash_attention import HEURISTIC_BLOCKS
+
+    seq_q, seq_kv, _ = dims
+    return min(HEURISTIC_BLOCKS[0], seq_q), min(HEURISTIC_BLOCKS[1], seq_kv)
+
+
 register_op(
     OpSpec(
         name="gemm",
@@ -258,6 +286,7 @@ register_op(
         timed_operands=_gemm_timed_operands,
         timed_fn=_gemm_timed_fn,
         pallas_run=_gemm_pallas_run,
+        default_blocks=_gemm_default_blocks,
     )
 )
 
@@ -271,5 +300,6 @@ register_op(
         timed_operands=_flash_timed_operands,
         timed_fn=_flash_timed_fn,
         pallas_run=_flash_pallas_run,
+        default_blocks=_flash_default_blocks,
     )
 )

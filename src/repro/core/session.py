@@ -41,6 +41,7 @@ from .executor import LaneExecutor, make_executor
 from .fault import RetryPolicy
 from .learn import ProposalFilter
 from .measure import MeasureEngine, MeasureStats
+from .ops import heuristic_state
 from .records import (
     TrialJournal,
     TuningRecords,
@@ -415,13 +416,21 @@ class TuningSession:
         budget = budget or Budget(max_fraction=0.001)
         tuner_cls = TUNERS[tuner_name]
         kwargs = dict(tuner_kwargs or {})
-        if warm_start and "s0" not in kwargs:
-            s0 = self.warm_start_state(
-                wl, space, cost.name, fingerprint=cost.measure_fingerprint()
-            )
-            if s0 is not None and "s0" in inspect.signature(
-                tuner_cls.__init__
-            ).parameters:
+        if "s0" not in kwargs and "s0" in inspect.signature(
+            tuner_cls.__init__
+        ).parameters:
+            s0 = None
+            if warm_start:
+                s0 = self.warm_start_state(
+                    wl, space, cost.name, fingerprint=cost.measure_fingerprint()
+                )
+            if s0 is None and cost.measured:
+                # a measured backend runs the schedule: from the untiled
+                # initial state one trial is one loop step per element
+                # (hours at model widths), so start at the kernel's
+                # heuristic blocks instead
+                s0 = heuristic_state(space)
+            if s0 is not None:
                 kwargs["s0"] = s0
         tuner = tuner_cls(space, cost, seed=self.seed if seed is None else seed,
                           **kwargs)

@@ -458,6 +458,17 @@ class FactoredSearchSpace(SearchSpace):
                 return s
         return self.initial_state()
 
+    def blocked_state(self, blocks: Sequence[int]) -> Optional[State]:
+        """The state that covers each factored dim in blocks of the given
+        size (grid factor outermost, deeper factors 1), via
+        :meth:`transplant` so a block that does not divide its dim
+        shrinks until it does; None when no legitimate state results."""
+        rows = [
+            [v // b, b] + [1] * (d - 2) if d > 1 else [v]
+            for v, b, d in zip(self._values, blocks, self._depths)
+        ]
+        return self.transplant(self.state_from_rows(rows))
+
     def transplant(self, s: State) -> Optional[State]:
         """Map a state tuned for *another* workload of this op into this
         space — the warm-start translation.

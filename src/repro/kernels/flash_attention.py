@@ -5,12 +5,18 @@ algorithm the model stack uses in pure JAX
 (models/common.chunked_causal_attention — which doubles as this kernel's
 oracle), expressed as a pl.pallas_call with explicit VMEM tiling:
 
+  layout:    Q (B, KV, G, S, hd) and K/V (B, KV, S, hd), head-major so
+             every block's last two dims are (rows, hd) — the TPU tiling
+             cannot take a single head out of a (heads, hd) tile
   grid:      (batch, kv_head, q_block)   — q blocks are parallel
-  BlockSpec: Q (1, block_q, G, hd) · K/V (1, block_k, 1, hd) streamed
-             through an inner fori_loop over kv blocks
-  scratch:   f32 accumulator (G, block_q, hd) + running max/sum (G, block_q)
+  BlockSpec: Q (G, block_q, hd) · K/V (S, hd) streamed through an inner
+             fori_loop over kv blocks
+  scratch:   f32 accumulator (G·block_q, hd) + running max/sum
+             (G·block_q, 1)
 
-Like the tiled GEMM, (block_q, block_k) are tunable — the same
+The G query heads of one KV head are folded into the rows of one 2-D
+matmul against each K/V block, so every contraction is a plain MXU
+matmul.  Like the tiled GEMM, (block_q, block_k) are tunable — the same
 GemmConfigSpace machinery applies (2-factor compositions); see
 tests/test_flash_kernel.py for the sweep.
 """
@@ -25,10 +31,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from . import check_interpret
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "HEURISTIC_BLOCKS"]
+
+#: (block_q, block_k) that dispatch runs without a tuning record
+HEURISTIC_BLOCKS = (256, 512)
+
+_NT = (((1,), (1,)), ((), ()))  # contract the last dims: a @ b.T
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
@@ -37,29 +47,38 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     """One (batch, kv_head, q_block) cell: stream kv blocks, online
     softmax into the VMEM accumulator."""
     iq = pl.program_id(2)
+    g, _, hd = q_ref.shape
+    rows = g * block_q
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, -1e30)
     l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0].astype(jnp.float32) * scale  # (block_q, g, hd)
+    q = q_ref[...].astype(jnp.float32).reshape(rows, hd) * scale
     n_k = seq_k // block_k
 
     def body(ik, _):
         sl = pl.dslice(ik * block_k, block_k)
-        kb = k_ref[0, sl, 0].astype(jnp.float32)  # (block_k, hd)
-        vb = v_ref[0, sl, 0].astype(jnp.float32)
-        # logits: (g, block_q, block_k)
-        logits = jnp.einsum("qgd,kd->gqk", q, kb)
+        kb = k_ref[sl, :].astype(jnp.float32)  # (block_k, hd)
+        vb = v_ref[sl, :].astype(jnp.float32)
+        logits = jax.lax.dot_general(
+            q, kb, _NT, preferred_element_type=jnp.float32
+        )  # (g * block_q, block_k); row r is query iq * block_q + r % block_q
         if causal:
-            q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            logits = jnp.where((q_pos >= k_pos)[None], logits, -1e30)
-        m_new = jnp.maximum(m_ref[...], logits.max(axis=-1))
-        p = jnp.exp(logits - m_new[..., None])
-        corr = jnp.exp(m_ref[...] - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + jnp.einsum("gqk,kd->gqd", p, vb)
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 0)
+            q_pos = iq * block_q + row % block_q
+            k_pos = ik * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, block_k), 1
+            )
+            logits = jnp.where(q_pos >= k_pos, logits, -1e30)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
+        p = jnp.exp(logits - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p, vb, preferred_element_type=jnp.float32
+        )
         m_ref[...] = m_new
         return ()
 
@@ -68,8 +87,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         n_k, ((iq + 1) * block_q + block_k - 1) // block_k
     )
     jax.lax.fori_loop(0, last, body, ())
-    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
-    o_ref[0, :, 0] = out.transpose(1, 0, 2).astype(out_dtype)  # (block_q, g, hd)
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    o_ref[...] = out.reshape(g, block_q, hd).astype(out_dtype)
 
 
 @functools.partial(
@@ -79,8 +98,8 @@ def flash_attention(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
-    block_q: int = 256,
-    block_k: int = 512,
+    block_q: int = HEURISTIC_BLOCKS[0],
+    block_k: int = HEURISTIC_BLOCKS[1],
     causal: bool = True,
     interpret: bool = False,
 ) -> jax.Array:
@@ -88,6 +107,7 @@ def flash_attention(
 
     GQA folds the H = KV x G query heads so each grid cell attends one
     KV head; K/V stream once per (batch, kv_head)."""
+    check_interpret(interpret)
     b, sq, h, hd = q.shape
     _, sk, kv, _ = k.shape
     g = h // kv
@@ -95,7 +115,9 @@ def flash_attention(
     block_k = min(block_k, sk)
     if sq % block_q or sk % block_k:
         raise ValueError(f"blocks ({block_q},{block_k}) must divide ({sq},{sk})")
-    qg = q.reshape(b, sq, kv, g, hd)
+    qh = q.reshape(b, sq, kv, g, hd).transpose(0, 2, 3, 1, 4)  # (b, kv, g, sq, hd)
+    kh = k.transpose(0, 2, 1, 3)  # (b, kv, sk, hd)
+    vh = v.transpose(0, 2, 1, 3)
     grid = (b, kv, sq // block_q)
 
     kernel = functools.partial(
@@ -107,26 +129,24 @@ def flash_attention(
         scale=1.0 / math.sqrt(hd),
         out_dtype=q.dtype,
     )
+    q_spec = pl.BlockSpec(
+        (None, None, g, block_q, hd), lambda ib, ih, iq: (ib, ih, 0, iq, 0)
+    )
+    kv_spec = pl.BlockSpec((None, None, sk, hd), lambda ib, ih, iq: (ib, ih, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, g, hd), lambda ib, ih, iq: (ib, iq, ih, 0, 0)),
-            pl.BlockSpec((1, sk, 1, hd), lambda ib, ih, iq: (ib, 0, ih, 0)),
-            pl.BlockSpec((1, sk, 1, hd), lambda ib, ih, iq: (ib, 0, ih, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, block_q, 1, g, hd), lambda ib, ih, iq: (ib, iq, ih, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, sq, kv, g, hd), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, sq, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((g, block_q, hd), jnp.float32),
-            pltpu.VMEM((g, block_q), jnp.float32),
-            pltpu.VMEM((g, block_q), jnp.float32),
+            pltpu.VMEM((g * block_q, hd), jnp.float32),
+            pltpu.VMEM((g * block_q, 1), jnp.float32),
+            pltpu.VMEM((g * block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
-    )(qg, k, v)
-    return out.reshape(b, sq, h, hd)
+    )(qh, kh, vh)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, hd)

@@ -1,7 +1,7 @@
 """Pallas TPU GEMM kernel with tuner-selected multi-level tiling.
 
 This is the compute hot-spot the paper optimizes, adapted to the TPU
-memory hierarchy (DESIGN.md §2):
+memory hierarchy:
 
   level 0 (grid):      (M/bm, N/bn, K/bk) macro-steps; k is the innermost
                        grid dimension so the f32 accumulator lives in
@@ -30,12 +30,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 from repro.core.config_space import TilingState
 
+from . import check_interpret
+
 __all__ = ["KernelConfig", "kernel_config_from_state", "gemm_pallas", "default_config"]
+
+#: TPU tiling: the last two dims of every block are multiples of
+#: (sublanes, lanes), or the whole array dim (the v5e compiler takes
+#: 8-row blocks for bf16 as well as f32)
+_SUBLANES, _LANES = 8, 128
+
+
+def _tiled(block: int, dim: int, align: int) -> bool:
+    return block == dim or block % align == 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,9 +69,21 @@ class KernelConfig:
         if c.block_m % c.sub_m or c.block_n % c.sub_n:
             raise ValueError("sub-tiles must divide blocks")
 
+    def tpu_aligned(self, m: int, k: int, n: int) -> bool:
+        """Whether every block and sub-tile meets the TPU tiling: the
+        compiler refuses anything else."""
+        c = self.resolved()
+        return (
+            _tiled(c.block_m, m, _SUBLANES)
+            and _tiled(c.block_k, k, _LANES)
+            and _tiled(c.block_n, n, _LANES)
+            and _tiled(c.sub_m, c.block_m, _SUBLANES)
+            and _tiled(c.sub_n, c.block_n, _LANES)
+        )
+
 
 def kernel_config_from_state(s: TilingState) -> KernelConfig:
-    """Interpret a tuner state as a kernel config (DESIGN.md §2)."""
+    """Interpret a tuner state as a kernel config."""
     cfg = KernelConfig(
         block_m=s.block_m,
         block_k=s.block_k,
@@ -77,19 +97,27 @@ def kernel_config_from_state(s: TilingState) -> KernelConfig:
 
 
 def default_config(m: int, k: int, n: int) -> KernelConfig:
-    """Heuristic fallback when no tuning record exists: largest
-    hardware-aligned blocks that fit the VMEM budget."""
+    """Heuristic fallback when no tuning record exists: per dim, the
+    whole dim when it is within the target, else the largest divisor
+    within the target that meets the TPU tiling (``tpu_aligned``).  A dim
+    with no such divisor keeps its largest plain divisor; dispatch then
+    sends the shape to XLA."""
 
-    def best_div(dim: int, target: int) -> int:
-        d = min(dim, target)
+    def block(dim: int, target: int, align: int) -> int:
+        if dim <= target:
+            return dim
+        for d in range(target - target % align, 0, -align):
+            if dim % d == 0:
+                return d
+        d = target
         while dim % d:
             d -= 1
         return d
 
     return KernelConfig(
-        block_m=best_div(m, 256),
-        block_k=best_div(k, 512),
-        block_n=best_div(n, 256),
+        block_m=block(m, 256, _SUBLANES),
+        block_k=block(k, 512, _LANES),
+        block_n=block(n, 256, _LANES),
     )
 
 
@@ -139,6 +167,7 @@ def gemm_pallas(
     out_dtype=None,
 ) -> jax.Array:
     """C = A @ B via the tiled Pallas kernel.  A: (M, K), B: (K, N)."""
+    check_interpret(interpret)
     (m, k), (k2, n) = a.shape, b.shape
     if k != k2:
         raise ValueError(f"contraction mismatch {a.shape} @ {b.shape}")
@@ -165,7 +194,7 @@ def gemm_pallas(
         out_specs=pl.BlockSpec((cfg.block_m, cfg.block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((cfg.block_m, cfg.block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

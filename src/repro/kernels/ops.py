@@ -6,15 +6,16 @@ FFN / expert matmul; ``models/common.attention_dispatch`` routes long
 self-attention through :func:`flash_schedule`.  Dispatch policy (trace
 time, all static):
 
-  1. If the process-global kernel policy disables Pallas (default on this
-     CPU-only container, and for full-scale dry-runs where interpret-mode
-     grids would explode the HLO), lower to the pure-XLA path — XLA picks
-     its own tiling.  On a real TPU deployment the policy flips on.
+  1. If the process-global kernel policy disables Pallas, lower to the
+     pure-XLA path — XLA picks its own tiling.  The default policy
+     follows the backend: on a TPU the Pallas kernels are on and compile
+     natively; elsewhere (the CPU tests) they are off, and a policy that
+     turns them on runs them in the Pallas interpreter.
   2. Otherwise consult the tuned record for the op's workload key
      (``records.workload_key_for`` under the policy's cost-backend
      namespace — written by `launch/tune.py`); fall back to the op's
-     heuristic default when there is no record, or to XLA when shapes
-     don't divide.
+     heuristic default when there is no record, or to XLA when the
+     blocks don't divide the shape or miss the TPU tiling.
 
 The lookup layer is **op-generic and memoized**: any op registered in
 `repro.core.ops` resolves its tuned schedule state through
@@ -43,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.records import add_change_listener, global_records, workload_key_for
+from . import interpret_default
 from .gemm import KernelConfig, default_config, gemm_pallas, kernel_config_from_state
 
 __all__ = [
@@ -61,8 +63,11 @@ __all__ = [
 
 @dataclasses.dataclass
 class KernelPolicy:
-    use_pallas: bool = False  # flipped on for TPU deployments / kernel tests
-    interpret: bool = True  # CPU container: interpret=True is the only mode
+    #: None follows the backend: on exactly when it is a TPU
+    use_pallas: Optional[bool] = None
+    #: None follows the backend: the interpreter exactly where there is
+    #: no TPU (``True`` on a TPU backend is refused by the kernels)
+    interpret: Optional[bool] = None
     cost_backend: str = "analytical_tpu_v5e"  # records namespace to consult
     #: ops that consult TuningRecords at trace time; an op not listed
     #: here always uses its heuristic default (the opt-in knob for
@@ -73,12 +78,23 @@ class KernelPolicy:
     #: without routing every projection GEMM through Pallas too
     pallas_ops: tuple[str, ...] = ("gemm", "flash")
 
+    def resolved(self) -> "KernelPolicy":
+        """This policy with the backend's rule filled in for every
+        field left at None."""
+        interp = interpret_default()
+        return dataclasses.replace(
+            self,
+            use_pallas=not interp if self.use_pallas is None else self.use_pallas,
+            interpret=interp if self.interpret is None else self.interpret,
+        )
+
 
 _POLICY = KernelPolicy()
 
 
 def kernel_policy() -> KernelPolicy:
-    return _POLICY
+    """The process-global policy, resolved against the backend."""
+    return _POLICY.resolved()
 
 
 def set_kernel_policy(policy: KernelPolicy) -> None:
@@ -159,9 +175,10 @@ def lookup_tuned_state(op: str, dims: tuple, dtype: str):
     caller falls back to its heuristic).  Memoized per
     ``(op, dims, dtype, backend)`` until records change.  Ops opt in
     via ``KernelPolicy.record_ops``."""
-    if op not in _POLICY.record_ops:
+    pol = kernel_policy()
+    if op not in pol.record_ops:
         return None
-    key = (op, tuple(dims), str(dtype), _POLICY.cost_backend)
+    key = (op, tuple(dims), str(dtype), pol.cost_backend)
     with _CACHE_LOCK:
         hit = _DISPATCH_CACHE.get(key, _MISS)
     if hit is not _MISS:
@@ -169,7 +186,7 @@ def lookup_tuned_state(op: str, dims: tuple, dtype: str):
         return hit
     note_dispatch(op, "store_lookups")
     st = global_records().lookup_state(
-        workload_key_for(op, tuple(dims), str(dtype), _POLICY.cost_backend)
+        workload_key_for(op, tuple(dims), str(dtype), pol.cost_backend)
     )
     if st is not None and _static_reject_record(op, dims, dtype, st):
         note_dispatch(op, "static_reject")
@@ -181,14 +198,16 @@ def lookup_tuned_state(op: str, dims: tuple, dtype: str):
 
 def _lookup_config(m: int, k: int, n: int, dtype: str) -> Optional[KernelConfig]:
     """GEMM spelling of the generic lookup: tuned state -> KernelConfig
-    (None when there is no record or the record doesn't map)."""
+    (None when there is no record, the record doesn't map, or its blocks
+    miss the TPU tiling — the heuristic serves then)."""
     st = lookup_tuned_state("gemm", (m, k, n), dtype)
     if st is None:
         return None
     try:
-        return kernel_config_from_state(st)
+        cfg = kernel_config_from_state(st)
     except (ValueError, AttributeError):  # foreign/unmappable record
         return None
+    return cfg if cfg.tpu_aligned(m, k, n) else None
 
 
 def flash_schedule(
@@ -213,9 +232,9 @@ def flash_schedule(
 def _pallas_ok(m: int, k: int, n: int, cfg: KernelConfig) -> bool:
     try:
         cfg.validate(m, k, n)
-        return True
     except ValueError:
         return False
+    return cfg.tpu_aligned(m, k, n)
 
 
 def _bwd(cfg, interpret, res, g):
@@ -268,8 +287,9 @@ def gemm(
     a2 = a.reshape((-1, k))
     m = a2.shape[0]
 
+    pol = kernel_policy()
     enabled = (
-        (_POLICY.use_pallas and "gemm" in _POLICY.pallas_ops)
+        (pol.use_pallas and "gemm" in pol.pallas_ops)
         if use_pallas is None
         else use_pallas
     )
@@ -279,7 +299,7 @@ def gemm(
         if _pallas_ok(m, k, n, cfg):
             src = "records" if tuned else ("explicit" if config else "heuristic")
             note_dispatch("gemm", src)
-            out = _gemm_pallas_diff(cfg, _POLICY.interpret, a2, b)
+            out = _gemm_pallas_diff(cfg, pol.interpret, a2, b)
             return out.reshape(lead + (n,))
         note_dispatch("gemm", "xla")
     out = jnp.dot(a2, b)

@@ -3,11 +3,15 @@
 Functions, not module-level constants — importing this module never
 touches jax device state (jax locks the device count at first backend
 init, and smoke tests must see 1 CPU device while the dry-run sees 512).
+Every axis is ``Auto``: the models place tensors through GSPMD sharding
+constraints (``repro.dist.api.constrain``), not through explicitly
+typed shardings.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.dist.api import MeshRules
 
@@ -21,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     "model" = TP/EP/SP."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def rules_for_mesh(mesh, sequence_parallel: bool = True) -> MeshRules:
@@ -43,4 +47,6 @@ def rules_for_mesh(mesh, sequence_parallel: bool = True) -> MeshRules:
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh(
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )

@@ -59,6 +59,7 @@ from repro.core.cost.measured import ExecutableCache
 from repro.core.records import global_records
 from repro.kernels.ops import kernel_policy
 from repro.models.api import Model
+from repro.utils.device import device_kind, enable_compile_cache
 
 __all__ = ["ServeEngine"]
 
@@ -152,7 +153,7 @@ class ServeEngine:
 
         return (
             f"serve/{kind}/{self._fp}/b{self.max_batch}/maxlen{self.max_len}"
-            f"/{kind[0]}{dim}/pad{int(self.pad_prompts)}"
+            f"/{kind[0]}{dim}/pad{int(self.pad_prompts)}/{device_kind()}"
             f"/jax{jax.__version__}/jaxlib{jaxlib.__version__}"
         )
 
@@ -251,17 +252,15 @@ class ServeEngine:
         return rep
 
     # -- serving ----------------------------------------------------------------
-    def generate(
+    def prefill(
         self,
         prompts: np.ndarray,
-        gen_tokens: int,
         prompt_lens: Optional[np.ndarray] = None,
-    ) -> np.ndarray:
-        """prompts: (B, P) int32; returns (B, gen_tokens).
-
-        ``prompt_lens`` (B,) marks each row's true length when rows are
-        already padded (the open-loop bench batches ragged requests);
-        defaults to full-width prompts."""
+    ):
+        """Run prompts (B, P) int32 through the bucketed prefill
+        executable; returns ``(logits, cache, bucket)`` with logits
+        (max_batch, 1, padded_vocab) f32 taken at each row's last real
+        position (see :meth:`generate` for ``prompt_lens``)."""
         prompts = np.asarray(prompts, np.int32)
         b, p = prompts.shape
         assert b <= self.max_batch
@@ -292,7 +291,6 @@ class ServeEngine:
                 jnp.dtype(self.cfg.compute_dtype),
             )
 
-        t0 = time.perf_counter()
         if self.pad_prompts:
             true_len = np.full((self.max_batch,), bucket, np.int32)
             true_len[:b] = lens
@@ -302,6 +300,22 @@ class ServeEngine:
             cache["prefill_len"] = jnp.asarray(bucket, jnp.int32)
         else:
             logits, cache = self._prefill_exec(bucket)(self.params, batch)
+        return logits, cache, bucket
+
+    def generate(
+        self,
+        prompts: np.ndarray,
+        gen_tokens: int,
+        prompt_lens: Optional[np.ndarray] = None,
+    ) -> np.ndarray:
+        """prompts: (B, P) int32; returns (B, gen_tokens).
+
+        ``prompt_lens`` (B,) marks each row's true length when rows are
+        already padded (the open-loop bench batches ragged requests);
+        defaults to full-width prompts."""
+        b = np.shape(prompts)[0]
+        t0 = time.perf_counter()
+        logits, cache, bucket = self.prefill(prompts, prompt_lens)
         logits.block_until_ready()
         prefill_s = time.perf_counter() - t0
         self.stats["prefill_s"].append(prefill_s)
@@ -324,7 +338,10 @@ class ServeEngine:
         return out[:b, :gen_tokens]
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> dict:
+    """Serve ``--requests`` random prompts once and print a summary.
+    Returns the engine, the prompts and the generated tokens for callers
+    that check them (``chip_smoke.py``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -336,8 +353,9 @@ def main() -> None:
                     help="persistent AOT executable cache directory")
     ap.add_argument("--buckets", default=None,
                     help="comma-separated prompt-length buckets to pre-warm")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -365,6 +383,7 @@ def main() -> None:
         f"compiles={rep['compiles']} disk_hits={rep['disk_hits']} "
         f"prewarm={rep['prewarm_s']:.2f}s; sample: {out[0][:8].tolist()}"
     )
+    return {"engine": engine, "prompts": prompts, "tokens": out}
 
 
 if __name__ == "__main__":

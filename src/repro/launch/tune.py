@@ -34,8 +34,10 @@ served from cache; the journal's append handle is closed when tuning
 ends.
 
 ``--cost xla`` swaps the analytical oracle for :class:`XLATimedCost` —
-real timed XLA:CPU programs built per op by the registry's
-``timed_fn``.  Its compile cost is kept off the hot path:
+real timed XLA programs built per op by the registry's ``timed_fn``, run
+on JAX's device (the TPU on a chip host, in this process: process lanes
+run JAX on the CPU), each search starting at the kernel's heuristic
+blocks.  Its compile cost is kept off the hot path:
 ``--n-build-workers`` compiles candidate batches in parallel, and a
 persistent compiled-program cache (``--compile-cache-dir``, default next
 to the journal; content keys carry the op) lets re-runs and process-lane
@@ -80,6 +82,7 @@ from repro.core.fault import RetryPolicy
 from repro.core.records import compile_cache_dir_for
 from repro.core.shard import parse_shard
 from repro.core.snapshot import TuneCheckpointer, TuneInterrupted
+from repro.utils.device import enable_compile_cache
 
 
 def _pad_dim(x: int) -> int:
@@ -135,7 +138,9 @@ def flash_workloads_for_arch(
     return [Workload("flash", (seq, seq, head_dim), dtype=dtype, label=label)]
 
 
-def main() -> None:
+def main(argv: Optional[list[str]] = None):
+    """Tune the workloads the arguments name; returns the
+    :class:`~repro.core.session.ArchTuneReport`."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--op", default="gemm",
                     help="which registered operator to tune (validated "
@@ -166,7 +171,7 @@ def main() -> None:
                          "'none' disables the persistent cache)")
     ap.add_argument("--cost", default="analytical", choices=["analytical", "xla"],
                     help="cost oracle: the op's analytical TPU model, or real "
-                         "timed XLA:CPU programs (XLATimedCost)")
+                         "XLA programs timed on JAX's device (XLATimedCost)")
     ap.add_argument("--n-build-workers", type=int, default=4,
                     help="parallel XLA compile threads per backend "
                          "(--cost xla only)")
@@ -236,7 +241,7 @@ def main() -> None:
                     help="seconds of real lane occupancy added per "
                          "measurement (SleepingCost wrapper) — gives "
                          "interrupt/kill tests a window to land in")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     try:
         shard = parse_shard(args.shard)
@@ -268,6 +273,7 @@ def main() -> None:
     journal = None if journal_path == "none" else TrialJournal(journal_path)
 
     if args.cost == "xla":
+        enable_compile_cache()
         cache_dir = args.compile_cache_dir
         if cache_dir is None:
             cache_dir = (
@@ -279,8 +285,8 @@ def main() -> None:
             cache_dir = None
 
         def cost_factory(space):
-            # float32: the honest CPU-timed stand-in (CPU has no native
-            # bf16 pipeline worth timing); seed fixes operand contents
+            # float32 operands (the CPU has no native bf16 pipeline
+            # worth timing); seed fixes operand contents
             return XLATimedCost(
                 space,
                 n_repeats=3,
@@ -371,6 +377,7 @@ def main() -> None:
         f"served_by_sibling={report.stats.n_served_by_sibling} "
         f"lane_failures={report.stats.n_failures})"
     )
+    return report
 
 
 if __name__ == "__main__":

@@ -170,16 +170,17 @@ def attention_dispatch(q, k, v, softcap: float = 0.0, chunk_threshold: int = 204
         and softcap == 0.0
         and s > chunk_threshold
     ):
-        from repro.kernels.flash_attention import flash_attention
+        from repro.kernels.flash_attention import HEURISTIC_BLOCKS, flash_attention
 
         tuned = flash_schedule(s, sk, hd, str(q.dtype))
         if tuned is not None:
             note_dispatch("flash", "records")
             return flash_attention(q, k, v, block_q=tuned[0], block_k=tuned[1],
                                    interpret=pol.interpret)
-        if s % 256 == 0 and sk % 512 == 0:
+        bq, bk = HEURISTIC_BLOCKS
+        if s % bq == 0 and sk % bk == 0:
             note_dispatch("flash", "heuristic")
-            return flash_attention(q, k, v, block_q=256, block_k=512,
+            return flash_attention(q, k, v, block_q=bq, block_k=bk,
                                    interpret=pol.interpret)
         note_dispatch("flash", "xla")
     if s > chunk_threshold:

@@ -243,9 +243,17 @@ def moe_apply_a2a(cfg: ArchConfig, p: dict, x: jax.Array, mesh, rules):
     axis delivers them to the expert owners, the expert FFN runs with
     FSDP-gathered weights, and the reverse all-to-all brings results home.
     shard_map collectives are differentiable (all_to_all^T = all_to_all,
-    all_gather^T = psum_scatter), so the same code serves training."""
-    from jax.experimental.shard_map import shard_map
+    all_gather^T = psum_scatter), so the same code serves training.
+
+    The shard_map runs on the mesh's devices with every axis ``Auto``
+    (GSPMD propagation, which ``constrain`` annotates for): under
+    ``Explicit`` axes, as ``jax.make_mesh`` gives by default, the
+    token-sharded (T, d) result could not be reshaped back to
+    (B, S, d)."""
+    from jax.sharding import AxisType
     from jax.sharding import PartitionSpec as P
+
+    mesh = mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
     b, s, d = x.shape
     t = b * s
@@ -317,12 +325,12 @@ def moe_apply_a2a(cfg: ArchConfig, p: dict, x: jax.Array, mesh, rules):
     w_spec = P("model", dp_axes, None) if r == 1 else P(None, dp_axes, None)
     in_specs = [tok_spec, P(tok_axes, None), P(tok_axes, None), w_spec, w_spec, w_spec]
     wg = p.get("wg")
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=tok_spec,
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(xf, top_e, top_w, p["wi"], wg if wg is not None else p["wi"], p["wo"])
     # (when ungated, wg input is a dummy alias; `local` ignores it)

@@ -8,6 +8,11 @@ import sys
 
 import pytest
 
+# the tests never write JAX's persistent compilation cache (the CLIs turn
+# it on through repro.utils.device.enable_compile_cache); subprocesses
+# inherit this
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
+
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 if SRC not in sys.path:
     sys.path.insert(0, os.path.abspath(SRC))

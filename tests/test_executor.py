@@ -396,3 +396,23 @@ def test_journal_context_manager_closes_and_reopens(tmp_path):
     j.record("w", s1, 2.0)  # lazily reopens
     j.close()
     assert len(TrialJournal(jpath)) == 2
+
+
+def test_process_lanes_refuse_a_device_backend_on_tpu(monkeypatch):
+    """One process per chip: workers run JAX on the CPU, so a backend
+    that times on the device is refused when this process holds a TPU."""
+    import jax
+
+    from repro.core.cost.measured import XLATimedCost
+
+    cost = XLATimedCost(GemmConfigSpace(64, 64, 64), n_repeats=1)
+    ex = ProcessExecutor()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        with pytest.raises(ValueError, match="holds the TPU"):
+            ex.run_wave(cost, [cost.space.initial_state()])
+        with pytest.raises(ValueError, match="holds the TPU"):
+            ex.warm_up(1, backend=cost)
+        assert not ex._workers  # refused before any worker started
+    finally:
+        ex.close()

@@ -137,3 +137,57 @@ def test_records_dispatch(tmp_path):
     finally:
         set_global_records(old)
         ops.set_kernel_policy(ops.KernelPolicy())
+
+
+def test_policy_follows_the_backend(monkeypatch):
+    """The default policy is the backend's rule: Pallas off and
+    interpreted on the CPU, on and compiled on a TPU; explicit fields
+    stay as given."""
+    pol = ops.KernelPolicy().resolved()
+    assert (pol.use_pallas, pol.interpret) == (False, True)
+    forced = ops.KernelPolicy(use_pallas=True, interpret=False).resolved()
+    assert (forced.use_pallas, forced.interpret) == (True, False)
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)  # a TPU
+    pol = ops.KernelPolicy().resolved()
+    assert (pol.use_pallas, pol.interpret) == (True, False)
+
+
+def test_interpret_refused_on_a_tpu_backend(monkeypatch):
+    import repro.kernels as kernels
+    from repro.core.cost.measured import PallasInterpretCost
+
+    monkeypatch.setattr(kernels, "interpret_default", lambda: False)  # a TPU
+    a, b = _rand((72, 128), "float32"), _rand((128, 128), "float32", 1)
+    with pytest.raises(ValueError, match="TPU backend"):
+        gemm_pallas(a, b, KernelConfig(8, 128, 128), interpret=True)
+    with pytest.raises(ValueError, match="TPU backend"):
+        PallasInterpretCost(GemmConfigSpace(64, 64, 64))
+
+
+@pytest.mark.parametrize(
+    "dims", [(8192, 11008, 4096), (8192, 4096, 11008), (2, 4096, 65536),
+             (8200, 4096, 4096), (63, 127, 65)], ids=str,
+)
+def test_default_config_meets_tpu_tiling(dims):
+    cfg = default_config(*dims)
+    assert cfg.tpu_aligned(*dims), cfg
+    cfg.validate(*dims)
+
+
+def test_unaligned_heuristic_dispatches_to_xla():
+    """A dim with no tiling-aligned divisor keeps an unaligned block,
+    which dispatch refuses: the shape runs on XLA, counted as such."""
+    m, k, n = 16, 1000, 128
+    assert not default_config(m, k, n).tpu_aligned(m, k, n)
+    assert default_config(8192, 11008, 4096).block_k == 256  # was 344
+    ops.set_kernel_policy(ops.KernelPolicy(use_pallas=True, interpret=True))
+    ops.reset_dispatch_stats()
+    try:
+        a, b = _rand((m, k), "float32"), _rand((k, n), "float32", 1)
+        out = ops.gemm(a, b)
+        assert ops.dispatch_stats()["gemm"]["xla"] == 1
+        np.testing.assert_allclose(np.asarray(out), np.asarray(a) @ np.asarray(b),
+                                   rtol=1e-4, atol=1e-3)
+    finally:
+        ops.set_kernel_policy(ops.KernelPolicy())
+        ops.reset_dispatch_stats()

@@ -229,3 +229,28 @@ def test_param_counts_full_configs():
     for name, (lo, hi) in expect.items():
         n = ARCHS[name].n_params()
         assert lo <= n <= hi, f"{name}: {n/1e9:.2f}B not in [{lo/1e9},{hi/1e9}]B"
+
+
+def test_init_params_jitted_matches_eager():
+    """init_params runs as one jitted program (no f32 copy of a stacked
+    weight is held); it draws the same parameters as the eager build."""
+    from repro.models import transformer as tf
+
+    cfg = get_arch("yi-6b").reduced(param_dtype="bfloat16")
+    key = jax.random.PRNGKey(3)
+    jitted = Model(cfg).init_params(key)
+    eager = tf.init_params(cfg, key)
+    flat_j, tree_j = jax.tree_util.tree_flatten(jitted)
+    flat_e, tree_e = jax.tree_util.tree_flatten(eager)
+    assert tree_j == tree_e
+    for a, b in zip(flat_j, flat_e):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+def test_local_mesh_axes_are_auto():
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_local_mesh
+
+    assert make_local_mesh(1, 1).axis_types == (AxisType.Auto, AxisType.Auto)

@@ -189,3 +189,28 @@ def test_tune_cli_writes_records(tmp_path):
 
     rec = TuningRecords(str(tmp_path / "r.json"))
     assert len(rec) >= 3
+
+
+def test_executable_keys_carry_the_device_kind(monkeypatch):
+    """A serve executable compiled for one device kind is never served
+    on another."""
+    import repro.launch.serve as serve
+
+    cfg, params = _reduced_model()
+    engine = ServeEngine(cfg, params, max_batch=2, max_len=24)
+    cpu_key = engine._raw_key("prefill", 16)
+    assert "/cpu/" in cpu_key
+    monkeypatch.setattr(serve, "device_kind", lambda: "TPU v5 lite")
+    assert engine._raw_key("prefill", 16) != cpu_key
+
+
+def test_prefill_seeds_generate():
+    """``prefill`` is the first half of ``generate``: its logits pick the
+    first generated token."""
+    cfg, params = _reduced_model()
+    engine = ServeEngine(cfg, params, max_batch=2, max_len=24)
+    prompts = np.arange(2 * 8, dtype=np.int32).reshape(2, 8) % cfg.vocab_size
+    logits, cache, bucket = engine.prefill(prompts)
+    assert logits.shape == (2, 1, cfg.padded_vocab) and bucket == 8
+    first = np.argmax(np.asarray(logits)[:, -1, : cfg.vocab_size], -1)
+    np.testing.assert_array_equal(engine.generate(prompts, 4)[:, 0], first)

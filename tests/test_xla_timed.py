@@ -152,3 +152,68 @@ def test_vmem_guard_is_inf_without_compiling():
     bad = TilingState((1, 1, 1, 4096), (1, 4096), (1, 4096, 1, 1))
     assert math.isinf(cost.cost(bad))
     assert cost.compile_stats()["compiles"] == 0
+
+
+def test_device_kind_scopes_keys_and_namespace(space, monkeypatch):
+    """What one device measured or compiled never answers on another."""
+    import repro.core.cost.measured as measured
+
+    s = space.initial_state()
+    cpu_key = ExecutableCache.content_key(space, "float32", s)
+    cpu = XLATimedCost(space, n_repeats=1)
+    assert cpu.name == "xla_cpu_timed"
+    monkeypatch.setattr(measured, "device_kind", lambda: "TPU v5 lite")
+    assert ExecutableCache.content_key(space, "float32", s) != cpu_key
+    tpu = XLATimedCost(space, n_repeats=1)
+    assert tpu.name == "xla_tpu_v5_lite_timed"
+    assert tpu.measure_fingerprint() != cpu.measure_fingerprint()
+
+
+def test_measured_search_starts_at_the_heuristic_blocks():
+    """From the untiled s0 a measured trial loops once per element; a
+    measured backend's search starts where dispatch runs without a
+    record, the analytical oracle's keeps the paper's s0."""
+    from repro.core import Budget, TuningSession, Workload
+    from repro.core.ops import heuristic_state
+
+    wl = Workload("gemm", (512, 1024, 512), dtype="float32")
+    space = wl.space()
+    measured = TuningSession(
+        cost_factory=lambda sp: XLATimedCost(sp, n_repeats=1), verbose=False
+    ).tune_workload(wl, "g-bfs", Budget(max_trials=1))
+    assert measured.trials[0].state == heuristic_state(space)
+    assert measured.trials[0].state.grid == (2, 2, 2)  # blocks (256, 512, 256)
+    oracle = TuningSession(verbose=False).tune_workload(
+        wl, "g-bfs", Budget(max_trials=1)
+    )
+    assert oracle.trials[0].state == space.initial_state()
+
+
+def test_compile_cache_helper(monkeypatch):
+    """The CLIs keep JAX's compilation cache at one fixed path in the
+    checkout unless JAX_COMPILATION_CACHE_DIR names one or the cache is
+    off; nothing here turns the real cache on."""
+    import types
+
+    import jax
+
+    from repro.utils import device
+
+    calls = []
+    fake = types.SimpleNamespace(
+        jax_enable_compilation_cache=True,
+        update=lambda *a: calls.append(a),
+    )
+    monkeypatch.setattr(jax, "config", fake)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    device.enable_compile_cache()
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fake.jax_enable_compilation_cache = False
+    device.enable_compile_cache()
+    assert calls == []
+    fake.jax_enable_compilation_cache = True
+    device.enable_compile_cache()
+    assert calls == [("jax_compilation_cache_dir", str(device.COMPILE_CACHE_DIR))]
+    assert device.COMPILE_CACHE_DIR.name == ".jax_cache"
+    assert (device.COMPILE_CACHE_DIR.parent / "src" / "repro").is_dir()
