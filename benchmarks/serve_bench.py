@@ -120,8 +120,8 @@ def _timed_engine(engine: ServeEngine, prompts: np.ndarray, gen: int,
     pre, dec = [], []
     for _ in range(repeats):
         engine.generate(prompts, gen)
-        pre.append(engine.last_timing["prefill_s"])
-        dec.append(engine.last_timing["decode_s"])
+        pre.append(engine.stats["prefill_s"][-1])
+        dec.append(engine.stats["decode_s"][-1])
     pre_s, dec_s = float(np.median(pre)), float(np.median(dec))
     return {
         "prefill_s": round(pre_s, 5),
@@ -172,8 +172,8 @@ def _replay_stream(engine: ServeEngine, arrivals, gen: int) -> dict:
         for r, (_, ln) in enumerate(batch):
             prompts[r, :ln] = (np.arange(ln) * 7 + r) % engine.cfg.vocab_size
         engine.generate(prompts, gen, prompt_lens=lens)
-        pre_s = engine.last_timing["prefill_s"]
-        dec_s = engine.last_timing["decode_s"]
+        pre_s = engine.stats["prefill_s"][-1]
+        dec_s = engine.stats["decode_s"][-1]
         sim_t += pre_s + dec_s
         n_batches += 1
         pre_lat.append(pre_s)

@@ -178,16 +178,18 @@ def serve_phase(argv, logits_tol: float = LOGITS_TOL) -> None:
 
     from repro.kernels import ops
     from repro.launch import serve
+    from repro.utils import spans
 
     ops.reset_dispatch_stats()
     res = serve.main(argv)
     engine, prompts, tokens = res["engine"], res["prompts"], res["tokens"]
     cfg = engine.cfg
-    t = engine.last_timing
+    call = [r for r in spans.snapshot() if r.name == "serve.generate"][-1].attrs
     rep = engine.cache_report()
     log(f"serve tokens={tokens.tolist()}")
-    log(f"serve prefill_s={t['prefill_s']:.4f} decode_s={t['decode_s']:.4f} "
-        f"prompt_bucket={t['prompt_bucket']} gen_bucket={t['gen_bucket']} "
+    log(f"serve prefill_s={engine.stats['prefill_s'][-1]:.4f} "
+        f"decode_s={engine.stats['decode_s'][-1]:.4f} "
+        f"prompt_bucket={call['prompt_bucket']} gen_bucket={call['gen_bucket']} "
         f"compiles={rep['compiles']} compile_s={rep['compile_s']:.2f}")
     stats = ops.dispatch_stats()
     for op, d in sorted(stats.items()):
@@ -196,7 +198,7 @@ def serve_phase(argv, logits_tol: float = LOGITS_TOL) -> None:
         d = stats.get(op, {})
         if not sum(d.get(s, 0) for s in ("records", "heuristic", "explicit")):
             raise RuntimeError(f"serve: {op} was never dispatched to Pallas")
-    if tokens.shape != (len(prompts), t["gen_bucket"]) or not (
+    if tokens.shape != (len(prompts), call["gen_bucket"]) or not (
         (tokens >= 0) & (tokens < cfg.vocab_size)
     ).all():
         raise RuntimeError(f"serve: bad tokens {tokens.shape}")

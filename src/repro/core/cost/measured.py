@@ -62,6 +62,7 @@ from typing import Optional
 
 
 from repro.utils.device import device_kind
+from repro.utils.spans import carry, span
 
 from ..space import SearchSpace, State
 from .base import CostBackend
@@ -330,9 +331,9 @@ class XLATimedCost(CostBackend):
         path) -- the traceable realization of the schedule comes from the
         op registry's ``timed_fn`` binding."""
         fn = self._opspec.timed_fn(self.space, s, self.dtype)
-        t0 = time.perf_counter()
-        compiled = self._jax.jit(fn).lower(*self._args).compile()
-        self.cache.count_compile(time.perf_counter() - t0)
+        with span("measure.compile") as sp:
+            compiled = self._jax.jit(fn).lower(*self._args).compile()
+        self.cache.count_compile(sp.seconds)
         return compiled
 
     def _fits_vmem(self, s: State) -> bool:
@@ -356,13 +357,14 @@ class XLATimedCost(CostBackend):
         fn = self.cache.get_mem(ckey, count=count_mem_hit)
         if fn is not None:
             return fn
-        fn = self.cache.get_disk(ckey)
+        with span("measure.load"):
+            fn = self.cache.get_disk(ckey)
         if fn is None:
             fn = self._build(s)
             self.cache.put_disk(ckey, fn)
         # warmup: never timed, but gated — a warm run on the cores would
         # contend with a sibling lane's in-flight timed region
-        with self._gate:
+        with span("measure.warmup"), self._gate:
             fn(*self._args).block_until_ready()
         self.cache.put_mem(ckey, fn)
         return fn
@@ -373,12 +375,13 @@ class XLATimedCost(CostBackend):
         processes sharing the lock file) off the cores while a
         measurement is on the clock."""
         total = 0.0
-        for _ in range(self.n_repeats):
-            with self._gate:
-                t0 = time.perf_counter()
-                fn(*self._args).block_until_ready()
-                total += time.perf_counter() - t0
-            self.cache.count_timed()
+        with span("measure.timed"):
+            for _ in range(self.n_repeats):
+                with self._gate:
+                    t0 = time.perf_counter()
+                    fn(*self._args).block_until_ready()
+                    total += time.perf_counter() - t0
+                self.cache.count_timed()
         return total / self.n_repeats
 
     def cost(self, s: State) -> float:
@@ -395,7 +398,7 @@ class XLATimedCost(CostBackend):
         if not self._fits_vmem(s):
             return math.inf
         fn = self._ensure(s)
-        with self._gate:
+        with span("measure.timed"), self._gate:
             t0 = time.perf_counter()
             fn(*self._args).block_until_ready()
             dt = time.perf_counter() - t0
@@ -431,7 +434,8 @@ class XLATimedCost(CostBackend):
             workers = min(self.n_build_workers, len(todo))
             with ThreadPoolExecutor(max_workers=workers) as ex:
                 # the prefetch charges the trial's disk-hit/compile event
-                for fut in [ex.submit(self._ensure, s, False) for s in todo]:
+                ensure = carry(self._ensure)  # pool spans join this root
+                for fut in [ex.submit(ensure, s, False) for s in todo]:
                     fut.result()
             todo = []
         by_key: dict[str, float] = {}
@@ -575,16 +579,18 @@ class PallasInterpretCost(CostBackend):
         fn = self.cache.get_mem(ckey)
         if fn is not None:
             return fn
-        fn = self.cache.get_disk(ckey)
+        with span("measure.load"):
+            fn = self.cache.get_disk(ckey)
         if fn is None:
-            t0 = time.perf_counter()
             traced = lambda *ops: self._opspec.pallas_run(
                 self.space, s, ops, interpret=True
             )
-            fn = self._jax.jit(traced).lower(*self._args).compile()
-            self.cache.count_compile(time.perf_counter() - t0)
+            with span("measure.compile") as sp:
+                fn = self._jax.jit(traced).lower(*self._args).compile()
+            self.cache.count_compile(sp.seconds)
             self.cache.put_disk(ckey, fn)
-        fn(*self._args).block_until_ready()  # warm: never timed
+        with span("measure.warmup"):
+            fn(*self._args).block_until_ready()  # never timed
         self.cache.put_mem(ckey, fn)
         return fn
 
@@ -597,9 +603,10 @@ class PallasInterpretCost(CostBackend):
         except ValueError:  # schedule the kernel refuses (bad blocks)
             self._bad.add(skey)
             return math.inf
-        t0 = time.perf_counter()
-        fn(*self._args).block_until_ready()
-        dt = time.perf_counter() - t0
+        with span("measure.timed"):
+            t0 = time.perf_counter()
+            fn(*self._args).block_until_ready()
+            dt = time.perf_counter() - t0
         self.cache.count_timed()
         return dt
 

@@ -36,7 +36,8 @@ measurement (an XLA compile easily outlives a 4 s charging cap).
   longer take down the tuning session.  Workers run JAX on the CPU: a
   chip belongs to one process, and the parent may hold it, so a backend
   that measures on the device is refused on process lanes when the
-  parent's backend is a TPU.
+  parent's backend is a TPU.  A worker's spans (``repro.utils.spans``)
+  stay in the worker: process lanes ship none back.
 
 Executors with ``real_time = True`` report *measured* per-lane wall
 seconds; the engine charges those to the search clock instead of the
@@ -54,6 +55,8 @@ import os
 import sys
 import time
 from typing import Optional, Sequence
+
+from repro.utils.spans import carry
 
 from .cost.base import CostBackend, backend_from_spec
 from .space import State
@@ -175,6 +178,7 @@ class ThreadExecutor(LaneExecutor):
                     kind="raise",
                 )
 
+        lane = carry(lane)  # the lane's spans join the wave's root
         threads = [
             threading.Thread(
                 target=lane, args=(i, s), daemon=True, name=f"measure-lane-{i}"
@@ -489,7 +493,7 @@ class ProcessExecutor(LaneExecutor):
                 box: list = [None]
                 t0 = time.perf_counter()
                 th = threading.Thread(
-                    target=deg_lane, args=(box, s, t0), daemon=True,
+                    target=carry(deg_lane), args=(box, s, t0), daemon=True,
                     name=f"degraded-lane-{i}",
                 )
                 th.start()

@@ -30,10 +30,11 @@ so a later session starts filtering from wave one instead of measuring
 from __future__ import annotations
 
 import os
-import time
 from typing import Optional, Sequence
 
 import numpy as np
+
+from repro.utils.spans import span
 
 from ..records import TrialJournal
 from ..space import SearchSpace, State
@@ -148,29 +149,31 @@ class ProposalFilter:
             if self._waves_since_check < self.retrain_every:
                 return False
         self._waves_since_check = 0
-        t0 = time.perf_counter()
-        try:
-            ds = build_dataset(
-                self.journal.path, self.space.op,
-                dtype=self.dtype, fingerprint=self.fingerprint,
-            )
-            if (
-                ds.counts.n_trainable < self.min_rows
-                or ds.counts.n_trainable <= self._rows_at_fit
-                or ds.n_features != self.space.n_features
-            ):
-                return False
-            model = RankingCostModel.fit_dataset(ds, **self.hyper)
-            if not model.is_fitted:
-                return False
-            self.model = model
-            self._rows_at_fit = ds.counts.n_trainable
-            self.n_retrains += 1
-            if self.cache_dir is not None:
-                model.save(self.cache_dir)
-            return True
-        finally:
-            self.learn_s += time.perf_counter() - t0
+        with span("measure.learn") as sp:
+            fitted = self._retrain()
+        self.learn_s += sp.seconds
+        return fitted
+
+    def _retrain(self) -> bool:
+        ds = build_dataset(
+            self.journal.path, self.space.op,
+            dtype=self.dtype, fingerprint=self.fingerprint,
+        )
+        if (
+            ds.counts.n_trainable < self.min_rows
+            or ds.counts.n_trainable <= self._rows_at_fit
+            or ds.n_features != self.space.n_features
+        ):
+            return False
+        model = RankingCostModel.fit_dataset(ds, **self.hyper)
+        if not model.is_fitted:
+            return False
+        self.model = model
+        self._rows_at_fit = ds.counts.n_trainable
+        self.n_retrains += 1
+        if self.cache_dir is not None:
+            model.save(self.cache_dir)
+        return True
 
     # -- selection ------------------------------------------------------------
     def select(
@@ -189,10 +192,10 @@ class ProposalFilter:
         n_keep = max(1, int(np.ceil(self.keep * n)))
         if n_keep >= n:
             return list(range(n)), []
-        t0 = time.perf_counter()
-        X = np.stack([self.space.features(s) for s in states])
-        scores = self.model.predict(X)
-        self.learn_s += time.perf_counter() - t0
+        with span("measure.learn") as sp:
+            X = np.stack([self.space.features(s) for s in states])
+            scores = self.model.predict(X)
+        self.learn_s += sp.seconds
         order = np.argsort(scores, kind="stable")
         kept = sorted(int(i) for i in order[:n_keep])
         skipped = [

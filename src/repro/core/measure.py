@@ -50,6 +50,8 @@ import math
 import time
 from typing import Optional, Sequence
 
+from repro.utils.spans import span
+
 from .analysis import ScheduleAnalyzer, analyzer_for_backend, should_prune
 from .space import State
 from .cost.base import CostBackend
@@ -388,6 +390,21 @@ class MeasureEngine:
         sessions (or other workloads sharing the journal) hit the cache.
         """
         assert len(states) <= self.n_workers, "wave larger than lane count"
+        with span("measure.funnel"):
+            outcomes, miss_idx, n_hits = self._funnel(states)
+        if miss_idx:
+            self._dispatch(states, miss_idx, outcomes)
+        done = [o for o in outcomes if o is not None]
+        self.stats.n_dispatched += len(miss_idx)
+        self.stats.n_cache_hits += n_hits
+        self.stats.n_waves += 1
+        self.stats.lane_busy_s += sum(o.lane_s for o in done)
+        self.stats.span_s += max((o.lane_s for o in done), default=0.0)
+        return done
+
+    def _funnel(self, states) -> tuple:
+        """The cache, static, learned and shard stages of one wave:
+        ``(outcomes, misses still to measure, cache hits)``."""
         if self.journal is not None and self.reload_every:
             self._waves_since_reload += 1
             if self._waves_since_reload >= self.reload_every:
@@ -418,30 +435,9 @@ class MeasureEngine:
             else:
                 miss_idx.append(i)
         if miss_idx and self.analyze != "off":
-            # static pre-filter: classify every miss before it occupies a
-            # lane; provably-bad candidates (ILLEGAL, or degenerate
-            # WASTEFUL) are rejected compile-free in prune mode and
-            # journaled as audit rows, anything else merely flagged
-            t0 = time.perf_counter()
-            kept: list[int] = []
-            for i in miss_idx:
-                s = states[i]
-                res = self.analyzer.analyze(s)
-                if self.analyze == "prune" and should_prune(res):
-                    outcomes[i] = MeasureOutcome(
-                        s, math.inf, False, 0.0, static=res.reason
-                    )
-                    self.stats.trials_avoided += 1
-                    if self.journal is not None and self.journal_key is not None:
-                        self.journal.record_static(
-                            self.journal_key, s, res.reason, op=self.backend.op
-                        )
-                else:
-                    if not res.ok:
-                        self.stats.n_static_flags += 1
-                    kept.append(i)
-            miss_idx = kept
-            self.stats.static_s += time.perf_counter() - t0
+            with span("measure.static") as sp:
+                miss_idx = self._static(states, miss_idx, outcomes)
+            self.stats.static_s += sp.seconds
         if self.learned_filter is not None and len(miss_idx) >= 2:
             # learned proposal filter: retrain at its cadence from the
             # journal rows accumulated so far (this very search's rows
@@ -505,22 +501,53 @@ class MeasureEngine:
                         )
                         self.stats.n_deferred_to_sibling += 1
             miss_idx = owned
-        if miss_idx:
-            # NOTE: self.timeout_s is the *simulated charging cap* (a slow
-            # config charges at most that much search clock); the real
-            # executors own their kill timeout separately — conflating the
-            # two would kill legitimately slow measurements (XLA compiles)
-            fault_fn = getattr(self.executor, "fault_stats", None)
-            fault_before = fault_fn() if callable(fault_fn) else None
-            attempts = dict.fromkeys(miss_idx, 0)
-            acc_lane_s = dict.fromkeys(miss_idx, 0.0)
-            pending = list(miss_idx)
-            while pending:
-                sub = [states[i] for i in pending]
+        return outcomes, miss_idx, n_hits
+
+    def _static(self, states, miss_idx: list, outcomes: list) -> list:
+        """The static pre-filter: classify every miss before it occupies
+        a lane; provably-bad candidates (ILLEGAL, or degenerate WASTEFUL)
+        are rejected compile-free in prune mode and journaled as audit
+        rows, anything else merely flagged.  Returns the misses kept."""
+        kept: list[int] = []
+        for i in miss_idx:
+            s = states[i]
+            res = self.analyzer.analyze(s)
+            if self.analyze == "prune" and should_prune(res):
+                outcomes[i] = MeasureOutcome(
+                    s, math.inf, False, 0.0, static=res.reason
+                )
+                self.stats.trials_avoided += 1
+                if self.journal is not None and self.journal_key is not None:
+                    self.journal.record_static(
+                        self.journal_key, s, res.reason, op=self.backend.op
+                    )
+            else:
+                if not res.ok:
+                    self.stats.n_static_flags += 1
+                kept.append(i)
+        return kept
+
+    def _dispatch(self, states, miss_idx: list, outcomes: list) -> None:
+        """Measure the wave's remaining misses on the lanes, retrying
+        transient failures, and book each verdict into ``outcomes`` and
+        the journal."""
+        # NOTE: self.timeout_s is the *simulated charging cap* (a slow
+        # config charges at most that much search clock); the real
+        # executors own their kill timeout separately — conflating the
+        # two would kill legitimately slow measurements (XLA compiles)
+        fault_fn = getattr(self.executor, "fault_stats", None)
+        fault_before = fault_fn() if callable(fault_fn) else None
+        attempts = dict.fromkeys(miss_idx, 0)
+        acc_lane_s = dict.fromkeys(miss_idx, 0.0)
+        pending = list(miss_idx)
+        while pending:
+            sub = [states[i] for i in pending]
+            with span("measure.lanes"):
                 compile_before = self.backend.compile_stats()
                 lanes = self.executor.run_wave(self.backend, sub)
                 self._fold_compile(lanes, compile_before)
                 self._note_stragglers(lanes)
+            with span("measure.journal"):
                 nxt: list[int] = []
                 backoffs: list[float] = []
                 for i, lane in zip(pending, lanes):
@@ -550,32 +577,24 @@ class MeasureEngine:
                     outcomes[i] = self._finalize(
                         s, lane, kind, attempts[i], acc_lane_s[i]
                     )
-                if nxt and backoffs and self.executor.real_time:
-                    # the retried lanes redispatch as one wave: sleep the
-                    # longest backoff for real; simulated lanes only
-                    # charged it to the clock above
-                    time.sleep(max(backoffs))
-                pending = nxt
-            if fault_before is not None:
-                after = fault_fn()
-                for key, attr in (
-                    ("n_respawns", "n_respawns"),
-                    ("n_spare_adoptions", "n_spare_adoptions"),
-                    ("n_degraded_lanes", "n_degraded_lanes"),
-                ):
-                    setattr(
-                        self.stats, attr,
-                        getattr(self.stats, attr)
-                        + after.get(key, 0) - fault_before.get(key, 0),
-                    )
-        done = [o for o in outcomes if o is not None]
-        self.stats.n_dispatched += len(miss_idx)
-        self.stats.n_cache_hits += n_hits
-        self.stats.n_waves += 1
-        span = max((o.lane_s for o in done), default=0.0)
-        self.stats.lane_busy_s += sum(o.lane_s for o in done)
-        self.stats.span_s += span
-        return done
+            if nxt and backoffs and self.executor.real_time:
+                # the retried lanes redispatch as one wave: sleep the
+                # longest backoff for real; simulated lanes only
+                # charged it to the clock above
+                time.sleep(max(backoffs))
+            pending = nxt
+        if fault_before is not None:
+            after = fault_fn()
+            for key, attr in (
+                ("n_respawns", "n_respawns"),
+                ("n_spare_adoptions", "n_spare_adoptions"),
+                ("n_degraded_lanes", "n_degraded_lanes"),
+            ):
+                setattr(
+                    self.stats, attr,
+                    getattr(self.stats, attr)
+                    + after.get(key, 0) - fault_before.get(key, 0),
+                )
 
     def cache_hit_rate(self) -> float:
         return self.stats.cache_hit_rate()

@@ -36,6 +36,8 @@ import inspect
 import math
 from typing import Callable, Optional, Sequence
 
+from repro.utils.spans import span
+
 from .cost import CostBackend
 from .executor import LaneExecutor, make_executor
 from .fault import RetryPolicy
@@ -322,211 +324,213 @@ class TuningSession:
         shard: Optional[ShardSpec] = None,
         shard_wait_s: float = 60.0,
     ) -> TuneResult:
-        if learned_filter not in ("off", "on"):
-            raise ValueError(
-                f"learned_filter must be 'off' or 'on', got {learned_filter!r}"
-            )
-        if shard is not None and not shard.enabled:
-            shard = None  # 0/1 is the unsharded engine, bit-identical
-        if shard is not None and self.journal is None:
-            raise ValueError(
-                "sharded tuning (shard I/N with N > 1) needs a shared journal "
-                "— siblings exchange measurements and done markers through it"
-            )
-        space = wl.space()
-        cost = self.cost_factory(space)
-        wkey = wl.key(cost.name)
-        if engine is not None and executor is not None and engine.executor is not executor:
-            # same convention as TuningContext: the engine owns the
-            # measurement model — reject conflicts, don't silently drop
-            raise ValueError(
-                "executor=... conflicts with the provided engine's executor"
-            )
-        if engine is not None and analyze != "off" and engine.analyze != analyze:
-            raise ValueError(
-                "analyze=... conflicts with the provided engine's analyze mode"
-            )
-        if engine is not None and retry is not None and retry.enabled and engine.retry != retry:
-            raise ValueError(
-                "retry=... conflicts with the provided engine's retry policy"
-            )
-        if engine is not None and learned_filter == "on" and engine.learned_filter is None:
-            raise ValueError(
-                "learned_filter='on' conflicts with the provided engine "
-                "(it has no ProposalFilter)"
-            )
-        if engine is not None and shard is not None and engine.shard != shard:
-            raise ValueError(
-                f"shard={shard} conflicts with the provided engine's "
-                f"{engine.shard}"
-            )
-        # each shard owns its own search state: a shard-suffixed snapshot
-        # identity keeps two hosts resuming one workload from colliding
-        tuner_id = (
-            tuner_name if shard is None
-            else f"{tuner_name}@shard{shard.index}of{shard.count}"
-        )
-        # -- crash-safe resume: serve finished workloads from their done
-        # snapshot, restore interrupted ones mid-search -----------------------
-        restore = None
-        if checkpointer is not None and resume:
-            payload = checkpointer.load(wkey, tuner_id)
-            if payload is not None and payload.get("done"):
-                result = _result_from_jsonable(payload["result"], space)
-                if self.verbose:
-                    print(
-                        f"[tune] {wl.label or wkey} {tuner_name}: "
-                        f"already complete (resumed from done snapshot, "
-                        f"best={result.best_cost:.3e}s trials={result.n_trials})"
-                    )
-                return result
-            restore = payload
-        elif checkpointer is not None:
-            # fresh run: stale snapshots (incl. a previous done marker)
-            # must not shadow this run for a later --resume
-            checkpointer.clear(wkey, tuner_id)
-        if engine is None:
-            flt = None
-            if learned_filter == "on":
-                # per-workload filter: the model's scope is this space's
-                # op/feature-width + the backend's dtype/fingerprint, and
-                # its cache lives next to the session journal
-                flt = ProposalFilter(
-                    space,
-                    self.journal,
-                    dtype=wl.dtype,
-                    fingerprint=cost.measure_fingerprint(),
-                    keep=filter_keep,
-                    retrain_every=filter_retrain_every,
-                    min_rows=filter_min_rows,
+        with span("tune.workload", workload=wl.label):
+            if learned_filter not in ("off", "on"):
+                raise ValueError(
+                    f"learned_filter must be 'off' or 'on', got {learned_filter!r}"
                 )
-            engine = MeasureEngine(
-                cost,
-                n_workers=n_workers,
-                journal=self.journal,
-                workload_key=wkey,
-                stats=stats,
-                executor=executor,
-                reload_every=reload_every,
-                analyze=analyze,
-                retry=retry,
-                learned_filter=flt,
-                shard=shard,
-            )
-        budget = budget or Budget(max_fraction=0.001)
-        tuner_cls = TUNERS[tuner_name]
-        kwargs = dict(tuner_kwargs or {})
-        if "s0" not in kwargs and "s0" in inspect.signature(
-            tuner_cls.__init__
-        ).parameters:
-            s0 = None
-            if warm_start:
-                s0 = self.warm_start_state(
-                    wl, space, cost.name, fingerprint=cost.measure_fingerprint()
+            if shard is not None and not shard.enabled:
+                shard = None  # 0/1 is the unsharded engine, bit-identical
+            if shard is not None and self.journal is None:
+                raise ValueError(
+                    "sharded tuning (shard I/N with N > 1) needs a shared journal "
+                    "— siblings exchange measurements and done markers through it"
                 )
-            if s0 is None and cost.measured:
-                # a measured backend runs the schedule: from the untiled
-                # initial state one trial is one loop step per element
-                # (hours at model widths), so start at the kernel's
-                # heuristic blocks instead
-                s0 = heuristic_state(space)
-            if s0 is not None:
-                kwargs["s0"] = s0
-        tuner = tuner_cls(space, cost, seed=self.seed if seed is None else seed,
-                          **kwargs)
-        checkpoint_fn = None
-        if checkpointer is not None:
-            def checkpoint_fn(t, ctx, _ck=checkpointer):
-                # periodic snapshot at the cadence; an interrupt always
-                # flushes a final one, then unwinds the whole session
-                if _ck.interrupted or ctx.round_idx % _ck.every_rounds == 0:
-                    _ck.save(
-                        wkey,
-                        tuner_id,
-                        {
-                            "tuner": tuner_name,
-                            "tuner_state": t.state_dict(),
-                            "ctx": ctx.snapshot(),
-                        },
-                        step=ctx.round_idx,
+            with span("tune.setup"):
+                space = wl.space()
+                cost = self.cost_factory(space)
+                wkey = wl.key(cost.name)
+                if engine is not None and executor is not None and engine.executor is not executor:
+                    # same convention as TuningContext: the engine owns the
+                    # measurement model — reject conflicts, don't silently drop
+                    raise ValueError(
+                        "executor=... conflicts with the provided engine's executor"
                     )
-                if _ck.interrupted:
-                    raise TuneInterrupted(wkey)
+                if engine is not None and analyze != "off" and engine.analyze != analyze:
+                    raise ValueError(
+                        "analyze=... conflicts with the provided engine's analyze mode"
+                    )
+                if engine is not None and retry is not None and retry.enabled and engine.retry != retry:
+                    raise ValueError(
+                        "retry=... conflicts with the provided engine's retry policy"
+                    )
+                if engine is not None and learned_filter == "on" and engine.learned_filter is None:
+                    raise ValueError(
+                        "learned_filter='on' conflicts with the provided engine "
+                        "(it has no ProposalFilter)"
+                    )
+                if engine is not None and shard is not None and engine.shard != shard:
+                    raise ValueError(
+                        f"shard={shard} conflicts with the provided engine's "
+                        f"{engine.shard}"
+                    )
+                # each shard owns its own search state: a shard-suffixed snapshot
+                # identity keeps two hosts resuming one workload from colliding
+                tuner_id = (
+                    tuner_name if shard is None
+                    else f"{tuner_name}@shard{shard.index}of{shard.count}"
+                )
+                # -- crash-safe resume: serve finished workloads from their done
+                # snapshot, restore interrupted ones mid-search -----------------------
+                restore = None
+                if checkpointer is not None and resume:
+                    payload = checkpointer.load(wkey, tuner_id)
+                    if payload is not None and payload.get("done"):
+                        result = _result_from_jsonable(payload["result"], space)
+                        if self.verbose:
+                            print(
+                                f"[tune] {wl.label or wkey} {tuner_name}: "
+                                f"already complete (resumed from done snapshot, "
+                                f"best={result.best_cost:.3e}s trials={result.n_trials})"
+                            )
+                        return result
+                    restore = payload
+                elif checkpointer is not None:
+                    # fresh run: stale snapshots (incl. a previous done marker)
+                    # must not shadow this run for a later --resume
+                    checkpointer.clear(wkey, tuner_id)
+                if engine is None:
+                    flt = None
+                    if learned_filter == "on":
+                        # per-workload filter: the model's scope is this space's
+                        # op/feature-width + the backend's dtype/fingerprint, and
+                        # its cache lives next to the session journal
+                        flt = ProposalFilter(
+                            space,
+                            self.journal,
+                            dtype=wl.dtype,
+                            fingerprint=cost.measure_fingerprint(),
+                            keep=filter_keep,
+                            retrain_every=filter_retrain_every,
+                            min_rows=filter_min_rows,
+                        )
+                    engine = MeasureEngine(
+                        cost,
+                        n_workers=n_workers,
+                        journal=self.journal,
+                        workload_key=wkey,
+                        stats=stats,
+                        executor=executor,
+                        reload_every=reload_every,
+                        analyze=analyze,
+                        retry=retry,
+                        learned_filter=flt,
+                        shard=shard,
+                    )
+                budget = budget or Budget(max_fraction=0.001)
+                tuner_cls = TUNERS[tuner_name]
+                kwargs = dict(tuner_kwargs or {})
+                if "s0" not in kwargs and "s0" in inspect.signature(
+                    tuner_cls.__init__
+                ).parameters:
+                    s0 = None
+                    if warm_start:
+                        s0 = self.warm_start_state(
+                            wl, space, cost.name, fingerprint=cost.measure_fingerprint()
+                        )
+                    if s0 is None and cost.measured:
+                        # a measured backend runs the schedule: from the untiled
+                        # initial state one trial is one loop step per element
+                        # (hours at model widths), so start at the kernel's
+                        # heuristic blocks instead
+                        s0 = heuristic_state(space)
+                    if s0 is not None:
+                        kwargs["s0"] = s0
+                tuner = tuner_cls(space, cost, seed=self.seed if seed is None else seed,
+                                  **kwargs)
+                checkpoint_fn = None
+                if checkpointer is not None:
+                    def checkpoint_fn(t, ctx, _ck=checkpointer):
+                        # periodic snapshot at the cadence; an interrupt always
+                        # flushes a final one, then unwinds the whole session
+                        if _ck.interrupted or ctx.round_idx % _ck.every_rounds == 0:
+                            _ck.save(
+                                wkey,
+                                tuner_id,
+                                {
+                                    "tuner": tuner_name,
+                                    "tuner_state": t.state_dict(),
+                                    "ctx": ctx.snapshot(),
+                                },
+                                step=ctx.round_idx,
+                            )
+                        if _ck.interrupted:
+                            raise TuneInterrupted(wkey)
 
-        result = tuner.tune(
-            budget, engine=engine, checkpoint_fn=checkpoint_fn, restore=restore
-        )
-        if shard is not None:
-            # elect-and-merge: publish this shard's best, wait for the
-            # siblings' done markers, and keep-best-merge the elected
-            # winner (lowest journaled cost, ties -> lowest shard index)
-            # into the records table.  Every shard runs this — the merge
-            # is idempotent, so no coordinator is needed.
-            root = shard_dir_for(self.journal.path)
-            write_done_marker(
-                root,
-                engine.journal_key,
-                shard,
-                None if result.best_state is None else result.best_state.as_lists(),
-                result.best_cost,
-                result.n_trials,
+            result = tuner.tune(
+                budget, engine=engine, checkpoint_fn=checkpoint_fn, restore=restore
             )
-            markers = await_markers(
-                root, engine.journal_key, shard, timeout_s=shard_wait_s
-            )
-            if len(markers) < shard.count and self.verbose:
-                missing = sorted(set(range(shard.count)) - set(markers))
-                print(
-                    f"[tune] {wl.label or wkey} shard {shard}: warning — "
-                    f"sibling shard(s) {missing} never reported within "
-                    f"{shard_wait_s:.0f}s; electing over the partial set"
+            if shard is not None:
+                # elect-and-merge: publish this shard's best, wait for the
+                # siblings' done markers, and keep-best-merge the elected
+                # winner (lowest journaled cost, ties -> lowest shard index)
+                # into the records table.  Every shard runs this — the merge
+                # is idempotent, so no coordinator is needed.
+                root = shard_dir_for(self.journal.path)
+                write_done_marker(
+                    root,
+                    engine.journal_key,
+                    shard,
+                    None if result.best_state is None else result.best_state.as_lists(),
+                    result.best_cost,
+                    result.n_trials,
                 )
-            # pick up the siblings' measurements before anyone reads best_state
-            self.journal.reload()
-            won = elect_best(markers)
-            if won is not None:
-                win_idx, win_lists, win_cost = won
+                markers = await_markers(
+                    root, engine.journal_key, shard, timeout_s=shard_wait_s
+                )
+                if len(markers) < shard.count and self.verbose:
+                    missing = sorted(set(range(shard.count)) - set(markers))
+                    print(
+                        f"[tune] {wl.label or wkey} shard {shard}: warning — "
+                        f"sibling shard(s) {missing} never reported within "
+                        f"{shard_wait_s:.0f}s; electing over the partial set"
+                    )
+                # pick up the siblings' measurements before anyone reads best_state
+                self.journal.reload()
+                won = elect_best(markers)
+                if won is not None:
+                    win_idx, win_lists, win_cost = won
+                    self.records.update(
+                        wkey,
+                        space.state_from_lists(win_lists),
+                        win_cost,
+                        tuner_name,
+                        result.n_trials,
+                        extra={
+                            "label": wl.label,
+                            "n_workers": engine.n_workers,
+                            "shard_winner": win_idx,
+                            "n_shards": shard.count,
+                        },
+                    )
+            elif result.best_state is not None and math.isfinite(result.best_cost):
                 self.records.update(
                     wkey,
-                    space.state_from_lists(win_lists),
-                    win_cost,
+                    result.best_state,
+                    result.best_cost,
                     tuner_name,
                     result.n_trials,
-                    extra={
-                        "label": wl.label,
-                        "n_workers": engine.n_workers,
-                        "shard_winner": win_idx,
-                        "n_shards": shard.count,
-                    },
+                    extra={"label": wl.label, "n_workers": engine.n_workers},
                 )
-        elif result.best_state is not None and math.isfinite(result.best_cost):
-            self.records.update(
-                wkey,
-                result.best_state,
-                result.best_cost,
-                tuner_name,
-                result.n_trials,
-                extra={"label": wl.label, "n_workers": engine.n_workers},
-            )
-        if checkpointer is not None:
-            # mark the workload finished AFTER records.update so a crash
-            # between the two re-runs the search instead of losing the record
-            checkpointer.save(
-                wkey,
-                tuner_id,
-                {"done": True, "tuner": tuner_name,
-                 "result": _result_to_jsonable(result)},
-                step=_DONE_STEP,
-            )
-        if self.verbose:
-            print(
-                f"[tune] {wl.label or wkey} {tuner_name}: "
-                f"best={result.best_cost:.3e}s trials={result.n_trials} "
-                f"frac={result.fraction:.5f} wall={result.wall_s:.1f}s "
-                f"clock={result.clock_s:.1f}s workers={result.n_workers} "
-                f"cache_hit={result.cache_hit_rate:.2f}"
-            )
-        return result
+            if checkpointer is not None:
+                # mark the workload finished AFTER records.update so a crash
+                # between the two re-runs the search instead of losing the record
+                checkpointer.save(
+                    wkey,
+                    tuner_id,
+                    {"done": True, "tuner": tuner_name,
+                     "result": _result_to_jsonable(result)},
+                    step=_DONE_STEP,
+                )
+            if self.verbose:
+                print(
+                    f"[tune] {wl.label or wkey} {tuner_name}: "
+                    f"best={result.best_cost:.3e}s trials={result.n_trials} "
+                    f"frac={result.fraction:.5f} wall={result.wall_s:.1f}s "
+                    f"clock={result.clock_s:.1f}s workers={result.n_workers} "
+                    f"cache_hit={result.cache_hit_rate:.2f}"
+                )
+            return result
 
     # -- whole architecture --------------------------------------------------
     def tune_arch(

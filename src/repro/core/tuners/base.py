@@ -36,6 +36,8 @@ import random
 import time
 from typing import Callable, Optional, Sequence
 
+from repro.utils.spans import span
+
 from ..space import SearchSpace, State
 from ..cost.base import CostBackend
 from ..measure import MeasureEngine
@@ -275,28 +277,29 @@ class TuningContext:
         path on the clock.  Raises :class:`BudgetExhausted` when the
         budget runs out mid-batch (the already-measured prefix is kept).
         """
-        fresh: list[State] = []
-        fresh_keys: set[str] = set()
-        for s in states:
-            key = s.key()
-            if key not in self.visited and key not in fresh_keys:
-                fresh.append(s)
-                fresh_keys.add(key)
-        i = 0
-        while i < len(fresh):
-            if self.done():
-                raise BudgetExhausted()
-            room = self.max_trials - len(self.trials)
-            wave = fresh[i : i + min(self.n_workers, room)]
-            outcomes = self.engine.measure_wave(wave)
-            self.clock_s += max(o.lane_s for o in outcomes)
-            for o in outcomes:
-                self.visited[o.state.key()] = o.cost
-                self.trials.append(Trial(o.state, o.cost, len(self.trials), self.clock_s))
-                if o.cost < self.best_cost:
-                    self.best_cost, self.best_state = o.cost, o.state
-            i += len(wave)
-        return [self.visited[s.key()] for s in states]
+        with span("tune.measure"):
+            fresh: list[State] = []
+            fresh_keys: set[str] = set()
+            for s in states:
+                key = s.key()
+                if key not in self.visited and key not in fresh_keys:
+                    fresh.append(s)
+                    fresh_keys.add(key)
+            i = 0
+            while i < len(fresh):
+                if self.done():
+                    raise BudgetExhausted()
+                room = self.max_trials - len(self.trials)
+                wave = fresh[i : i + min(self.n_workers, room)]
+                outcomes = self.engine.measure_wave(wave)
+                self.clock_s += max(o.lane_s for o in outcomes)
+                for o in outcomes:
+                    self.visited[o.state.key()] = o.cost
+                    self.trials.append(Trial(o.state, o.cost, len(self.trials), self.clock_s))
+                    if o.cost < self.best_cost:
+                        self.best_cost, self.best_state = o.cost, o.state
+                i += len(wave)
+            return [self.visited[s.key()] for s in states]
 
     def measure(self, s: State) -> float:
         """Single-state convenience wrapper over :meth:`measure_many`."""

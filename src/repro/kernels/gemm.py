@@ -198,4 +198,7 @@ def gemm_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # the HLO instruction's name, which the benchmark's trace reduction
+        # finds the kernel by (benchmarks/chip/trace.py)
+        name="gemm_pallas",
     )(a, b)

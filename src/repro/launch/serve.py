@@ -60,6 +60,7 @@ from repro.core.records import global_records
 from repro.kernels.ops import kernel_policy
 from repro.models.api import Model
 from repro.utils.device import device_kind, enable_compile_cache
+from repro.utils.spans import span
 
 __all__ = ["ServeEngine"]
 
@@ -123,7 +124,6 @@ class ServeEngine:
             "prefill_buckets": {},  # bucket -> call count
             "bucket_misses": 0,   # prompts no configured bucket could hold
         }
-        self.last_timing: dict = {}
         if prewarm is None:
             prewarm = bool(self.prompt_buckets or self.gen_buckets)
         if prewarm:
@@ -166,9 +166,9 @@ class ServeEngine:
             return fn
         fn = self.cache.get_disk(ckey)
         if fn is None:
-            t0 = time.perf_counter()
-            fn = build()
-            self.cache.count_compile(time.perf_counter() - t0)
+            with span("serve.compile") as sp:
+                fn = build()
+            self.cache.count_compile(sp.seconds)
             self.cache.put_disk(ckey, fn)
         self.cache.put_mem(ckey, fn)
         return fn
@@ -238,12 +238,12 @@ class ServeEngine:
         """Resolve every configured (prefill, decode) bucket executable
         now — from disk on a warm restart (zero fresh compiles), from a
         compile on the first ever run."""
-        t0 = time.perf_counter()
-        for p in self.prompt_buckets or ():
-            self._prefill_exec(p)
-        for g in self.gen_buckets or ():
-            self._decode_exec(g)
-        self.prewarm_s = time.perf_counter() - t0
+        with span("serve.prewarm") as sp:
+            for p in self.prompt_buckets or ():
+                self._prefill_exec(p)
+            for g in self.gen_buckets or ():
+                self._decode_exec(g)
+        self.prewarm_s = sp.seconds
 
     def cache_report(self) -> dict:
         rep = dict(self.cache.stats())
@@ -252,15 +252,10 @@ class ServeEngine:
         return rep
 
     # -- serving ----------------------------------------------------------------
-    def prefill(
-        self,
-        prompts: np.ndarray,
-        prompt_lens: Optional[np.ndarray] = None,
-    ):
-        """Run prompts (B, P) int32 through the bucketed prefill
-        executable; returns ``(logits, cache, bucket)`` with logits
-        (max_batch, 1, padded_vocab) f32 taken at each row's last real
-        position (see :meth:`generate` for ``prompt_lens``)."""
+    def _stage(self, prompts, prompt_lens):
+        """Pad the prompts into their bucket and put them on the device:
+        ``(bucket, the prefill executable's arguments, the cache entries
+        that padding adds)``."""
         prompts = np.asarray(prompts, np.int32)
         b, p = prompts.shape
         assert b <= self.max_batch
@@ -290,16 +285,31 @@ class ServeEngine:
                 (self.max_batch, self.cfg.encoder_len, self.cfg.d_model),
                 jnp.dtype(self.cfg.compute_dtype),
             )
+        if not self.pad_prompts:
+            return bucket, (self.params, batch), {}
+        true_len = np.full((self.max_batch,), bucket, np.int32)
+        true_len[:b] = lens
+        last_idx = jnp.asarray(true_len - 1, jnp.int32)
+        pad_state = {
+            "valid_len": jnp.asarray(true_len, jnp.int32),
+            "prefill_len": jnp.asarray(bucket, jnp.int32),
+        }
+        return bucket, (self.params, batch, last_idx), pad_state
 
-        if self.pad_prompts:
-            true_len = np.full((self.max_batch,), bucket, np.int32)
-            true_len[:b] = lens
-            last_idx = jnp.asarray(true_len - 1, jnp.int32)
-            logits, cache = self._prefill_exec(bucket)(self.params, batch, last_idx)
-            cache["valid_len"] = jnp.asarray(true_len, jnp.int32)
-            cache["prefill_len"] = jnp.asarray(bucket, jnp.int32)
-        else:
-            logits, cache = self._prefill_exec(bucket)(self.params, batch)
+    def prefill(
+        self,
+        prompts: np.ndarray,
+        prompt_lens: Optional[np.ndarray] = None,
+    ):
+        """Run prompts (B, P) int32 through the bucketed prefill
+        executable; returns ``(logits, cache, bucket)`` with logits
+        (max_batch, 1, padded_vocab) f32 taken at each row's last real
+        position (see :meth:`generate` for ``prompt_lens``)."""
+        with span("serve.stage"):
+            bucket, args, pad_state = self._stage(prompts, prompt_lens)
+        with span("serve.launch"):
+            logits, cache = self._prefill_exec(bucket)(*args)
+        cache.update(pad_state)
         return logits, cache, bucket
 
     def generate(
@@ -314,27 +324,26 @@ class ServeEngine:
         already padded (the open-loop bench batches ragged requests);
         defaults to full-width prompts."""
         b = np.shape(prompts)[0]
-        t0 = time.perf_counter()
-        logits, cache, bucket = self.prefill(prompts, prompt_lens)
-        logits.block_until_ready()
-        prefill_s = time.perf_counter() - t0
-        self.stats["prefill_s"].append(prefill_s)
-        self.stats["prefill_buckets"][bucket] = (
-            self.stats["prefill_buckets"].get(bucket, 0) + 1
-        )
+        with span("serve.generate", batch=b) as call:
+            with span("serve.prefill") as sp:
+                logits, cache, bucket = self.prefill(prompts, prompt_lens)
+                with span("serve.wait"):
+                    logits.block_until_ready()
+            self.stats["prefill_s"].append(sp.seconds)
+            self.stats["prefill_buckets"][bucket] = (
+                self.stats["prefill_buckets"].get(bucket, 0) + 1
+            )
 
-        g = _bucket_for(gen_tokens, self.gen_buckets)
-        t0 = time.perf_counter()
-        toks_dev = self._decode_exec(g)(self.params, cache, logits)
-        out = np.asarray(toks_dev)  # the one host transfer
-        decode_s = time.perf_counter() - t0
-        self.stats["decode_s"].append(decode_s)
-        self.last_timing = {
-            "prefill_s": prefill_s,
-            "decode_s": decode_s,
-            "prompt_bucket": bucket,
-            "gen_bucket": g,
-        }
+            g = _bucket_for(gen_tokens, self.gen_buckets)
+            call.attrs.update(prompt_bucket=bucket, gen_bucket=g)
+            with span("serve.decode") as sp:
+                with span("serve.launch"):
+                    toks_dev = self._decode_exec(g)(self.params, cache, logits)
+                with span("serve.wait"):
+                    toks_dev.block_until_ready()
+                with span("serve.fetch"):
+                    out = np.asarray(toks_dev)  # the one host transfer
+            self.stats["decode_s"].append(sp.seconds)
         return out[:b, :gen_tokens]
 
 

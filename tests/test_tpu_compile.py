@@ -72,3 +72,29 @@ def test_flash_attention_compiles_for_v5e(one_chip):
         one_chip, (b, s, h, hd), (b, s, kv, hd), (b, s, kv, hd),
     )
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kind", ["gemm", "flash"])
+def test_kernel_instruction_names_match_the_trace_reduction(one_chip, kind):
+    """The benchmark finds each kernel in a device trace by its HLO
+    instruction name (``benchmarks/chip/trace.py``); a rename would
+    silence ``gemm_roofline.*`` and ``flash_roofline.prefill``.  Compiled
+    through the program's own dispatch, at yi-6b's prefill shapes."""
+    from benchmarks.chip.trace import _KERNELS
+    from repro.models.common import attention_dispatch
+
+    saved = ops.kernel_policy()
+    ops.set_kernel_policy(ops.KernelPolicy(use_pallas=True, interpret=False))
+    try:
+        if kind == "gemm":
+            m, k, n = PREFILL_TOKENS, YI.d_model, YI.d_ff
+            text = _compile(ops.gemm, one_chip, (m, k), (k, n)).as_text()
+        else:
+            b, s, h, kv, hd = 2, 4096, YI.n_heads, YI.n_kv_heads, YI.resolved_head_dim
+            text = _compile(attention_dispatch, one_chip,
+                            (b, s, h, hd), (b, s, kv, hd), (b, s, kv, hd)).as_text()
+    finally:
+        ops.set_kernel_policy(saved)
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert calls and all(_KERNELS[kind].match(c) for c in calls), calls[:2]
