@@ -190,7 +190,8 @@ def serve_phase(argv, logits_tol: float = LOGITS_TOL) -> None:
     log(f"serve prefill_s={engine.stats['prefill_s'][-1]:.4f} "
         f"decode_s={engine.stats['decode_s'][-1]:.4f} "
         f"prompt_bucket={call['prompt_bucket']} gen_bucket={call['gen_bucket']} "
-        f"compiles={rep['compiles']} compile_s={rep['compile_s']:.2f}")
+        f"compiles={rep['compiles']} compile_s={rep['compile_s']:.2f} "
+        f"stream={rep['stream']}")
     stats = ops.dispatch_stats()
     for op, d in sorted(stats.items()):
         log(f"serve dispatch {op} " + " ".join(f"{k}={v}" for k, v in sorted(d.items())))

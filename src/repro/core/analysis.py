@@ -105,6 +105,12 @@ def dtype_in_bytes(dtype: Optional[str], default: int = 2) -> int:
 # rely on bit-identical values.
 
 
+#: the v5e compiler's scoped VMEM limit: the working set one kernel may
+#: take (``TpuSpec.vmem_bytes``, ``XLATimedCost.vmem_guard_bytes`` and
+#: the GEMM kernel's heuristic blocks all read it)
+VMEM_BUDGET_BYTES = 16 << 20
+
+
 def gemm_working_set_bytes(block_m: int, block_k: int, block_n: int,
                            in_bytes: int = 2) -> int:
     """Double-buffered A/B blocks plus the f32 accumulator."""

@@ -29,7 +29,7 @@ import math
 
 import numpy as np
 
-from ..analysis import ScheduleAnalyzer, gemm_working_set_bytes
+from ..analysis import VMEM_BUDGET_BYTES, ScheduleAnalyzer, gemm_working_set_bytes
 from ..config_space import GemmConfigSpace, TilingState
 from .base import CostBackend
 
@@ -59,7 +59,7 @@ class TpuSpec:
     vpu_flops = 19.7e12  # elementwise f32 throughput (softmax path)
     hbm_bw = 819e9  # B/s
     ici_bw = 50e9  # B/s per link (used by the distributed roofline)
-    vmem_bytes = 16 * 1024 * 1024  # usable VMEM budget for one kernel
+    vmem_bytes = VMEM_BUDGET_BYTES  # usable VMEM budget for one kernel
     sublane = {2: 16, 4: 8}  # dtype bytes -> sublane granularity
     lane = 128
     mxu_k = 128  # contraction granularity fed to the systolic array

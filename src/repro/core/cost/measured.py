@@ -64,6 +64,7 @@ from typing import Optional
 from repro.utils.device import device_kind
 from repro.utils.spans import carry, span
 
+from ..analysis import VMEM_BUDGET_BYTES
 from ..space import SearchSpace, State
 from .base import CostBackend
 
@@ -295,7 +296,7 @@ class XLATimedCost(CostBackend):
         space: SearchSpace,
         n_repeats: int = 3,
         dtype: str = "float32",
-        vmem_guard_bytes: int = 16 * 1024 * 1024,
+        vmem_guard_bytes: int = VMEM_BUDGET_BYTES,
         seed: int = 0,
         n_build_workers: int = 4,
         cache_dir: Optional[str] = None,

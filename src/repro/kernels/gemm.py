@@ -18,28 +18,47 @@ A :class:`TilingState` from the tuner maps onto (bm, bk, bn, sub_m,
 sub_n) via :func:`kernel_config_from_state`.  The kernel is validated
 against ``ref.py`` in interpret mode on CPU (tests sweep shapes/dtypes);
 on a real TPU the same code JITs natively.
+
+B may also be a stack of layers, ``(L, K, N)``, with the layer's index as
+an operand: the index is scalar-prefetched and the B index map reads
+that layer's blocks straight from the stack, so a decode step's GEMM
+streams its weights from HBM once instead of after a copy of the layer.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.analysis import VMEM_BUDGET_BYTES, gemm_working_set_bytes
 from repro.core.config_space import TilingState
 
 from . import check_interpret
 
-__all__ = ["KernelConfig", "kernel_config_from_state", "gemm_pallas", "default_config"]
+__all__ = [
+    "KernelConfig", "kernel_config_from_state", "gemm_pallas", "default_config",
+    "STREAM_M", "STREAM_TILE_BYTES",
+]
 
 #: TPU tiling: the last two dims of every block are multiples of
 #: (sublanes, lanes), or the whole array dim (the v5e compiler takes
 #: 8-row blocks for bf16 as well as f32)
 _SUBLANES, _LANES = 8, 128
+
+
+#: GEMMs of at most this many rows stream B: a bf16 GEMM of M rows does
+#: about M FLOPs per byte of B, and the v5e ridge is 197 TFLOP/s over
+#: 819 GB/s, about 240 FLOPs per byte
+STREAM_M = 128
+#: least bytes of B a streaming grid step moves, so that the fixed cost
+#: of a step is small against its copy
+STREAM_TILE_BYTES = 2 << 20
 
 
 def _tiled(block: int, dim: int, align: int) -> bool:
@@ -96,12 +115,41 @@ def kernel_config_from_state(s: TilingState) -> KernelConfig:
     return cfg
 
 
-def default_config(m: int, k: int, n: int) -> KernelConfig:
-    """Heuristic fallback when no tuning record exists: per dim, the
-    whole dim when it is within the target, else the largest divisor
-    within the target that meets the TPU tiling (``tpu_aligned``).  A dim
-    with no such divisor keeps its largest plain divisor; dispatch then
-    sends the shape to XLA."""
+def _aligned_divisors(dim: int, align: int) -> list[int]:
+    """Ascending divisors of ``dim`` that meet the TPU tiling: the
+    multiples of ``align``, and the whole dim."""
+    out = [d for d in range(align, dim, align) if dim % d == 0]
+    return out + [dim]
+
+
+def _stream_config(m: int, k: int, n: int, itemsize: int) -> Optional[KernelConfig]:
+    """Blocks for a GEMM whose time is reading B (M <= ``STREAM_M``): all
+    of M; the whole of K, else its largest aligned divisor that fits, so
+    that A is read once; the narrowest aligned N block that moves
+    ``STREAM_TILE_BYTES`` of B a step (else the widest that fits), each
+    within ``VMEM_BUDGET_BYTES`` double-buffered.  None when nothing fits."""
+    ns = _aligned_divisors(n, _LANES)
+    for bk in reversed(_aligned_divisors(k, _LANES)):
+        fit = [bn for bn in ns
+               if gemm_working_set_bytes(m, bk, bn, itemsize) <= VMEM_BUDGET_BYTES]
+        if fit:
+            bn = next((bn for bn in fit if bk * bn * itemsize >= STREAM_TILE_BYTES),
+                      fit[-1])
+            return KernelConfig(block_m=m, block_k=bk, block_n=bn)
+    return None
+
+
+def default_config(m: int, k: int, n: int, itemsize: int = 2) -> KernelConfig:
+    """Heuristic fallback when no tuning record exists.  At most
+    ``STREAM_M`` rows, the streaming blocks of :func:`_stream_config`.
+    Otherwise, per dim, the whole dim when it is within the target, else
+    the largest divisor within the target that meets the TPU tiling
+    (``tpu_aligned``).  A dim with no such divisor keeps its largest
+    plain divisor; dispatch then sends the shape to XLA."""
+    if m <= STREAM_M:
+        cfg = _stream_config(m, k, n, itemsize)
+        if cfg is not None:
+            return cfg
 
     def block(dim: int, target: int, align: int) -> int:
         if dim <= target:
@@ -156,6 +204,17 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int, sub_m: int,
         o_ref[...] = acc_ref[...].astype(out_dtype)
 
 
+def _vmem_limit(cfg: KernelConfig, in_bytes: int, out_bytes: int) -> Optional[int]:
+    """``vmem_limit_bytes`` for a kernel whose double-buffered blocks,
+    output and accumulator leave less than a quarter of the compiler's
+    scoped limit spare; None keeps the compiler's own."""
+    need = (gemm_working_set_bytes(cfg.block_m, cfg.block_k, cfg.block_n, in_bytes)
+            + 2 * cfg.block_m * cfg.block_n * out_bytes)
+    if need <= VMEM_BUDGET_BYTES * 3 // 4:
+        return None
+    return need + VMEM_BUDGET_BYTES // 2
+
+
 @functools.partial(
     jax.jit, static_argnames=("config", "interpret", "out_dtype")
 )
@@ -165,10 +224,16 @@ def gemm_pallas(
     config: KernelConfig,
     interpret: bool = False,
     out_dtype=None,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """C = A @ B via the tiled Pallas kernel.  A: (M, K), B: (K, N)."""
+    """C = A @ B via the tiled Pallas kernel.  A: (M, K), B: (K, N); or B
+    a stack (L, K, N) and ``layer`` the index of the B to use, which the
+    kernel reads in place."""
     check_interpret(interpret)
-    (m, k), (k2, n) = a.shape, b.shape
+    stacked = b.ndim == 3
+    if stacked == (layer is None):
+        raise ValueError(f"B {b.shape} needs a layer index exactly when it is stacked")
+    (m, k), (k2, n) = a.shape, b.shape[-2:]
     if k != k2:
         raise ValueError(f"contraction mismatch {a.shape} @ {b.shape}")
     cfg = config.resolved()
@@ -177,28 +242,46 @@ def gemm_pallas(
     n_k = k // cfg.block_k
     grid = (m // cfg.block_m, n // cfg.block_n, n_k)
 
-    kernel = functools.partial(
+    body = functools.partial(
         _gemm_kernel,
         n_k=n_k,
         sub_m=cfg.sub_m,
         sub_n=cfg.sub_n,
         out_dtype=out_dtype,
     )
-    return pl.pallas_call(
-        kernel,
+    if stacked:
+        # the layer index is scalar-prefetched into SMEM, where the B
+        # index map reads it: each block is copied from the stack itself
+        prefetch = (jnp.reshape(layer, (1,)).astype(jnp.int32),)
+        b_spec = pl.BlockSpec((None, cfg.block_k, cfg.block_n),
+                              lambda i, j, kk, li: (li[0], kk, j))
+        kernel = lambda li, *refs: body(*refs)
+    else:
+        prefetch = ()
+        b_spec = pl.BlockSpec((cfg.block_k, cfg.block_n), lambda i, j, kk: (kk, j))
+        kernel = body
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((cfg.block_m, cfg.block_k), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((cfg.block_k, cfg.block_n), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((cfg.block_m, cfg.block_k), lambda i, j, kk, *_: (i, kk)),
+            b_spec,
         ],
-        out_specs=pl.BlockSpec((cfg.block_m, cfg.block_n), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        out_specs=pl.BlockSpec((cfg.block_m, cfg.block_n), lambda i, j, kk, *_: (i, j)),
         scratch_shapes=[pltpu.VMEM((cfg.block_m, cfg.block_n), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(
+                cfg, max(a.dtype.itemsize, b.dtype.itemsize), jnp.dtype(out_dtype).itemsize
+            ),
         ),
         interpret=interpret,
         # the HLO instruction's name, which the benchmark's trace reduction
         # finds the kernel by (benchmarks/chip/trace.py)
         name="gemm_pallas",
-    )(a, b)
+    )(*prefetch, a, b)

@@ -31,6 +31,12 @@ XLA fallback drove each dispatch; the serving bench surfaces them.
 The GEMM op is differentiable either way: the Pallas path installs a
 custom_vjp whose backward passes are themselves tiled GEMMs (dA = g Bᵀ,
 dB = Aᵀ g) so tuned kernels serve training too.
+
+A decode step hands ``gemm`` one layer of its stacked weights as a
+:class:`StackedWeight`.  The Pallas path reads that layer's blocks from
+the stack in place (counted as ``stream``); the XLA path slices the
+layer out, which XLA fuses into its dot.  The in-place path serves
+inference only: it has no backward pass.
 """
 
 from __future__ import annotations
@@ -38,7 +44,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +55,7 @@ from .gemm import KernelConfig, default_config, gemm_pallas, kernel_config_from_
 
 __all__ = [
     "gemm",
+    "StackedWeight",
     "KernelPolicy",
     "set_kernel_policy",
     "kernel_policy",
@@ -111,7 +118,7 @@ _DISPATCH_CACHE: dict[tuple, object] = {}
 _DISPATCH_STATS: dict[str, dict[str, int]] = {}
 _STAT_FIELDS = (
     "records", "heuristic", "xla", "memo_hits", "store_lookups",
-    "static_reject",
+    "static_reject", "stream",
 )
 
 
@@ -128,7 +135,8 @@ add_change_listener(invalidate_dispatch_cache)
 def note_dispatch(op: str, source: str) -> None:
     """Count one trace-time dispatch decision for ``op``:
     ``source`` in {"records", "heuristic", "xla"} (plus internal
-    memo/store counters)."""
+    memo/store counters, and ``stream``: a GEMM that read its layer of
+    stacked weights in place)."""
     with _CACHE_LOCK:
         per_op = _DISPATCH_STATS.setdefault(
             op, {f: 0 for f in _STAT_FIELDS}
@@ -242,8 +250,9 @@ def _bwd(cfg, interpret, res, g):
     m, k = a.shape
     n = b.shape[1]
     # backward GEMMs get their own tuned configs (shapes differ)
-    cfg_da = _lookup_config(m, n, k, str(g.dtype)) or default_config(m, n, k)
-    cfg_db = _lookup_config(k, m, n, str(g.dtype)) or default_config(k, m, n)
+    isz = g.dtype.itemsize
+    cfg_da = _lookup_config(m, n, k, str(g.dtype)) or default_config(m, n, k, isz)
+    cfg_db = _lookup_config(k, m, n, str(g.dtype)) or default_config(k, m, n, isz)
     da = (
         gemm_pallas(g, b.T, cfg_da, interpret=interpret)
         if _pallas_ok(m, n, k, cfg_da)
@@ -269,9 +278,17 @@ def _gemm_fwd(cfg, interpret, a, b):
 _gemm_pallas_diff.defvjp(_gemm_fwd, _bwd)
 
 
+class StackedWeight(NamedTuple):
+    """Layer ``index`` of the stacked weights ``w`` (L, K, N), as a
+    ``gemm`` operand that is read in place where the kernel can."""
+
+    w: jax.Array
+    index: jax.Array
+
+
 def gemm(
     a: jax.Array,
-    b: jax.Array,
+    b: jax.Array | StackedWeight,
     config: Optional[KernelConfig] = None,
     use_pallas: Optional[bool] = None,
 ) -> jax.Array:
@@ -279,11 +296,13 @@ def gemm(
 
     Higher-rank LHS is flattened to 2-D and restored — every dense layer
     in `repro.models` funnels through here."""
-    if a.ndim < 2 or b.ndim != 2:
-        raise ValueError(f"gemm expects (.., K) @ (K, N), got {a.shape} @ {b.shape}")
+    stacked = isinstance(b, StackedWeight)
+    w = b.w if stacked else b
+    if a.ndim < 2 or w.ndim != 2 + stacked:
+        raise ValueError(f"gemm expects (.., K) @ (K, N), got {a.shape} @ {w.shape}")
     lead = a.shape[:-1]
     k = a.shape[-1]
-    n = b.shape[-1]
+    n = w.shape[-1]
     a2 = a.reshape((-1, k))
     m = a2.shape[0]
 
@@ -295,12 +314,17 @@ def gemm(
     )
     if enabled:
         tuned = None if config is not None else _lookup_config(m, k, n, str(a.dtype))
-        cfg = config or tuned or default_config(m, k, n)
+        cfg = config or tuned or default_config(m, k, n, w.dtype.itemsize)
         if _pallas_ok(m, k, n, cfg):
-            src = "records" if tuned else ("explicit" if config else "heuristic")
-            note_dispatch("gemm", src)
-            out = _gemm_pallas_diff(cfg, pol.interpret, a2, b)
+            if stacked:
+                note_dispatch("gemm", "stream")
+                out = gemm_pallas(a2, w, cfg, interpret=pol.interpret, layer=b.index)
+            else:
+                note_dispatch("gemm", "records" if tuned else ("explicit" if config else "heuristic"))
+                out = _gemm_pallas_diff(cfg, pol.interpret, a2, b)
             return out.reshape(lead + (n,))
         note_dispatch("gemm", "xla")
+    if stacked:  # XLA fuses the slice of the layer into the dot
+        b = jax.lax.dynamic_index_in_dim(w, b.index, 0, keepdims=False)
     out = jnp.dot(a2, b)
     return out.reshape(lead + (n,))

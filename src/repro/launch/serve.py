@@ -57,7 +57,7 @@ import numpy as np
 from repro.configs.registry import get_arch
 from repro.core.cost.measured import ExecutableCache
 from repro.core.records import global_records
-from repro.kernels.ops import kernel_policy
+from repro.kernels.ops import dispatch_stats, kernel_policy
 from repro.models.api import Model
 from repro.utils.device import device_kind, enable_compile_cache
 from repro.utils.spans import span
@@ -246,7 +246,12 @@ class ServeEngine:
         self.prewarm_s = sp.seconds
 
     def cache_report(self) -> dict:
+        """The executable cache's counters, and ``stream``: the GEMM
+        dispatches traced in this process that read a layer of stacked
+        weights in place (``dispatch_stats``; an executable loaded from
+        disk traces nothing)."""
         rep = dict(self.cache.stats())
+        rep["stream"] = dispatch_stats().get("gemm", {}).get("stream", 0)
         rep["prewarm_s"] = self.prewarm_s
         rep["bucket_misses"] = self.stats["bucket_misses"]
         return rep

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.dist.api import constrain, logical
+from repro.kernels.ops import StackedWeight
 from repro.models import common as cm
 
 __all__ = [
@@ -752,6 +753,36 @@ def prefill(cfg: ArchConfig, params: dict, batch: dict, max_len: int,
     return logits, cache
 
 
+#: the per-layer groups of dense projections ({name: {"w", ["b"]}}), which
+#: a decode step reads in place from the stacked params
+_DENSE_GROUPS = ("attn", "cross", "mlp")
+
+
+def _decode_layer(layers: dict, li: jax.Array, moe: bool) -> dict:
+    """Layer ``li`` of the stacked params for one decode step.  Each dense
+    projection's weight stays in its stack as an ``ops.StackedWeight``,
+    which the GEMM reads in place; everything else (norms, biases, the
+    router and expert weights) is sliced out."""
+
+    def take(a):
+        return jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)
+
+    def dense(p):
+        out = {"w": StackedWeight(p["w"], li)}
+        if "b" in p:
+            out["b"] = take(p["b"])
+        return out
+
+    return {
+        name: (
+            {proj: dense(p) for proj, p in group.items()}
+            if name in _DENSE_GROUPS and not (moe and name == "mlp")
+            else jax.tree_util.tree_map(take, group)
+        )
+        for name, group in layers.items()
+    }
+
+
 def decode_step(cfg: ArchConfig, params: dict, cache: dict, tokens: jax.Array):
     """One token for every sequence.  tokens: (B, 1).  Returns
     (logits (B,1,V), new_cache).
@@ -760,7 +791,9 @@ def decode_step(cfg: ArchConfig, params: dict, cache: dict, tokens: jax.Array):
     updates its slice in place (dynamic_update_index) — XLA's while-loop
     state aliasing then keeps ONE cache buffer live instead of the
     xs+ys pair a scan-over-cache would hold (2x cache = 10.7 GB/device
-    on qwen2-72b decode_32k)."""
+    on qwen2-72b decode_32k).  The dense projections' weights are not
+    scanned: each GEMM reads its layer from the stacked params in place
+    (``_decode_layer``), so no layer is copied out before its kernel."""
     b = tokens.shape[0]
     x = embed_tokens(cfg, params, tokens)
     pos = cache["len"]
@@ -780,10 +813,12 @@ def decode_step(cfg: ArchConfig, params: dict, cache: dict, tokens: jax.Array):
         x = x + jnp.take(params["pos_table"], positions[:, 0] % params["pos_table"].shape[0], axis=0)[:, None]
     moe = cfg.family == "moe"
     has_cross = cfg.family == "encdec"
+    layers = params["layers"]
 
     def body(carry, scanned):
-        x, k_all, v_all, li = carry
-        layer_p = scanned["p"]
+        x, k_all, v_all = carry
+        li = scanned["li"]
+        layer_p = _decode_layer(layers, li, moe)
         kv = {
             "k": jax.lax.dynamic_index_in_dim(k_all, li, 0, keepdims=False),
             "v": jax.lax.dynamic_index_in_dim(v_all, li, 0, keepdims=False),
@@ -798,14 +833,13 @@ def decode_step(cfg: ArchConfig, params: dict, cache: dict, tokens: jax.Array):
         )
         k_all = jax.lax.dynamic_update_index_in_dim(k_all, new_kv["k"], li, 0)
         v_all = jax.lax.dynamic_update_index_in_dim(v_all, new_kv["v"], li, 0)
-        return (x2, k_all, v_all, li + 1), None
+        return (x2, k_all, v_all), None
 
-    scanned = {"p": params["layers"]}
+    scanned = {"li": jnp.arange(cfg.n_layers, dtype=jnp.int32)}
     if has_cross:
         scanned["cross_k"], scanned["cross_v"] = cache["cross_k"], cache["cross_v"]
-    (x, new_k, new_v, _), _ = cm.scan_or_unroll(
-        cfg.scan_layers, body,
-        (x, cache["k"], cache["v"], jnp.zeros((), jnp.int32)), scanned,
+    (x, new_k, new_v), _ = cm.scan_or_unroll(
+        cfg.scan_layers, body, (x, cache["k"], cache["v"]), scanned,
     )
     logits = lm_logits(cfg, params, x)
     new_cache = dict(cache)

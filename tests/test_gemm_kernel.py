@@ -176,8 +176,11 @@ def test_default_config_meets_tpu_tiling(dims):
 
 def test_unaligned_heuristic_dispatches_to_xla():
     """A dim with no tiling-aligned divisor keeps an unaligned block,
-    which dispatch refuses: the shape runs on XLA, counted as such."""
-    m, k, n = 16, 1000, 128
+    which dispatch refuses: the shape runs on XLA, counted as such.  (At
+    most ``STREAM_M`` rows K is one whole block, which the tiling takes:
+    the unaligned block needs more rows.)"""
+    m, k, n = 256, 1000, 128
+    assert default_config(16, k, n).tpu_aligned(16, k, n)
     assert not default_config(m, k, n).tpu_aligned(m, k, n)
     assert default_config(8192, 11008, 4096).block_k == 256  # was 344
     ops.set_kernel_policy(ops.KernelPolicy(use_pallas=True, interpret=True))
@@ -191,3 +194,98 @@ def test_unaligned_heuristic_dispatches_to_xla():
     finally:
         ops.set_kernel_policy(ops.KernelPolicy())
         ops.reset_dispatch_stats()
+
+
+# -- layer-indexed B: the decode step's in-place weight reads -----------------
+
+
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("m", [2, 32])
+@pytest.mark.parametrize(
+    "k,n,blocks",
+    [
+        (43 * 256, 384, None),  # yi's K of 11008; N of 3 x 128; the streaming blocks
+        (3 * 256, 5 * 128, (256, 128)),  # several K and N steps through the stack
+    ],
+    ids=["k11008_n384_stream", "k768_n640_small_blocks"],
+)
+def test_layer_indexed_gemm_matches_dot(m, k, n, blocks, layer):
+    """The kernel reading layer ``l`` of a stacked (L, K, N) B in place
+    equals ``x @ w[l]``, at the first and the last layer."""
+    n_layers = 3
+    li = 0 if layer == "first" else n_layers - 1
+    a = _rand((m, k), "float32")
+    w = _rand((n_layers, k, n), "float32", seed=1)
+    cfg = default_config(m, k, n, itemsize=4) if blocks is None else KernelConfig(m, *blocks)
+    assert cfg.tpu_aligned(m, k, n), cfg
+    out = gemm_pallas(a, w, cfg, interpret=True, layer=jnp.int32(li))
+    want = np.asarray(a, np.float64) @ np.asarray(w[li], np.float64)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4, atol=1e-3 * np.sqrt(k / 128))
+
+
+@pytest.mark.parametrize("m", [4, 256])
+def test_stacked_gemm_dispatch_counts_stream(m):
+    """``ops.gemm`` on a StackedWeight: with Pallas on, one ``stream``
+    dispatch that reads the layer in place, whatever the rows (the
+    streaming blocks at 4, the per-dim blocks at 256); with it off, XLA's
+    dot of the sliced layer.  Both equal ``x @ w[l]``."""
+    a = _rand((m, 256), "float32")
+    w = _rand((2, 256, 384), "float32", seed=1)
+    want = np.asarray(a) @ np.asarray(w[1])
+    sw = ops.StackedWeight(w, jnp.int32(1))
+    ops.reset_dispatch_stats()
+    try:
+        np.testing.assert_allclose(np.asarray(ops.gemm(a, sw)), want, rtol=1e-4, atol=1e-3)
+        assert ops.dispatch_stats().get("gemm", {}).get("stream", 0) == 0
+        ops.set_kernel_policy(ops.KernelPolicy(use_pallas=True, interpret=True))
+        np.testing.assert_allclose(np.asarray(ops.gemm(a, sw)), want, rtol=1e-4, atol=1e-3)
+        assert ops.dispatch_stats()["gemm"]["stream"] == 1
+    finally:
+        ops.set_kernel_policy(ops.KernelPolicy())
+        ops.reset_dispatch_stats()
+
+
+def _decode_gemms(arch: str, m: int) -> list:
+    """(M, K, N) of every GEMM of one decode step of ``arch``: the seven
+    per-layer projections of its dense blocks and the head."""
+    from repro.configs.registry import get_arch
+
+    c = get_arch(arch)
+    d, hd, f = c.d_model, c.resolved_head_dim, c.d_ff
+    kn = {(d, c.n_heads * hd), (d, c.n_kv_heads * hd), (c.n_heads * hd, d),
+          (d, f), (f, d), (d, c.padded_vocab)}
+    return [(m, k, n) for k, n in sorted(kn)]
+
+
+DECODE_GEMMS = [
+    (arch, dims)
+    for arch, m in (("yi-6b", 32), ("yi-6b", 2), ("nemotron-4-15b", 2), ("nemotron-4-15b", 32))
+    for dims in _decode_gemms(arch, m)
+]
+
+
+@pytest.mark.parametrize(
+    "arch,dims", DECODE_GEMMS, ids=[f"{a}_{'x'.join(map(str, d))}" for a, d in DECODE_GEMMS]
+)
+def test_streaming_blocks_on_decode_shapes(arch, dims):
+    """The heuristic's blocks at a decode shape meet the TPU tiling, move
+    at least ``STREAM_TILE_BYTES`` of bf16 B a grid step, and keep the
+    double-buffered working set within the VMEM budget."""
+    from repro.core.analysis import VMEM_BUDGET_BYTES, gemm_working_set_bytes
+    from repro.kernels.gemm import STREAM_M, STREAM_TILE_BYTES
+
+    m, k, n = dims
+    assert m <= STREAM_M
+    cfg = default_config(m, k, n)
+    assert cfg.tpu_aligned(m, k, n), cfg
+    cfg.validate(m, k, n)
+    assert cfg.block_m == m
+    assert cfg.block_k * cfg.block_n * 2 >= STREAM_TILE_BYTES, cfg
+    assert gemm_working_set_bytes(cfg.block_m, cfg.block_k, cfg.block_n, 2) <= VMEM_BUDGET_BYTES
+
+
+def test_prefill_shapes_keep_their_blocks():
+    """Above ``STREAM_M`` rows the heuristic is the per-dim rule it was."""
+    assert default_config(8192, 4096, 11008) == KernelConfig(256, 512, 256)
+    assert default_config(8192, 11008, 4096) == KernelConfig(256, 256, 256)
+    assert default_config(8192, 6144, 24576) == KernelConfig(256, 512, 256)

@@ -117,6 +117,70 @@ def test_decode_matches_teacher_forcing(arch):
     assert max(errs) < 5e-3, errs
 
 
+def _decode_logits(arch: str, seed: int = 2):
+    """Reduced ``arch`` in bf16: prefill 6 tokens of a batch of 2, then one
+    decode step's logits, and the gemm dispatch counts of that step."""
+    from repro.kernels import ops
+
+    cfg = get_arch(arch).reduced(param_dtype="bfloat16", compute_dtype="bfloat16")
+    model = Model(cfg)
+    params = model.init_params(jax.random.PRNGKey(seed))
+    b, s = 2, 6
+    batch = {"tokens": (jnp.arange(b * s, dtype=jnp.int32).reshape(b, s) * 7) % 50}
+    if cfg.family == "encdec":
+        batch["enc_frames"] = jnp.ones((b, cfg.encoder_len, cfg.d_model), jnp.float32) * 0.01
+    _, cache = model.prefill(params, batch, max_len=s + 2)
+    tok = jnp.full((b, 1), 3, jnp.int32)
+    ops.reset_dispatch_stats()
+    logits, _ = model.decode_step(params, cache, tok)
+    return np.asarray(logits, np.float32), ops.dispatch_stats().get("gemm", {})
+
+
+@pytest.mark.parametrize(
+    "arch,pallas",
+    [("yi-6b", True), ("yi-6b", False), ("qwen3-moe-235b-a22b", False), ("whisper-tiny", False)],
+)
+def test_decode_reads_layer_weights_in_place(arch, pallas, monkeypatch):
+    """A decode step that hands each dense projection its stacked weight
+    and layer index gives the logits of one that slices every layer's
+    weights out first (the staged path), within bf16 tolerance: with the
+    Pallas kernels on (interpreted) and off; dense, MoE (experts stay
+    sliced) and encoder-decoder (cross attention) blocks."""
+    from repro.kernels import ops
+    from repro.models import transformer as tf
+
+    ops.set_kernel_policy(ops.KernelPolicy(use_pallas=pallas, interpret=True))
+    try:
+        in_place, stats = _decode_logits(arch)
+        monkeypatch.setattr(tf, "_DENSE_GROUPS", ())  # every weight sliced
+        staged, staged_stats = _decode_logits(arch)
+    finally:
+        ops.set_kernel_policy(ops.KernelPolicy())
+        ops.reset_dispatch_stats()
+    assert staged_stats.get("stream", 0) == 0
+    if pallas:
+        assert stats["stream"] > 0
+    assert np.isfinite(in_place).all()
+    scale = float(np.max(np.abs(staged)))
+    np.testing.assert_allclose(in_place, staged, rtol=0, atol=2e-2 * scale)
+
+
+def test_decode_step_streams_seven_projections():
+    """With Pallas on, the traced decode step of a dense decoder sends its
+    seven per-layer projections (q, k, v, o, wi, wg, wo) to the kernel in
+    place, once each in the scan body; the head is a plain 2-D GEMM."""
+    from repro.kernels import ops
+
+    ops.set_kernel_policy(ops.KernelPolicy(use_pallas=True, interpret=True))
+    try:
+        _, stats = _decode_logits("yi-6b")
+    finally:
+        ops.set_kernel_policy(ops.KernelPolicy())
+        ops.reset_dispatch_stats()
+    assert stats["stream"] == 7, stats
+    assert stats["heuristic"] == 1, stats
+
+
 # -----------------------------------------------------------------------------
 # SSD core
 # -----------------------------------------------------------------------------

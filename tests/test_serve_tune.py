@@ -120,6 +120,23 @@ def test_serve_prewarm_zero_compiles_on_restart(tmp_path, clean_dispatch):
     assert warm.cache_report()["compiles"] == 0  # serving stayed warm
 
 
+def test_cache_report_counts_stream_dispatches(clean_dispatch):
+    """With Pallas on, building the decode executable traces the seven
+    per-layer projections of a dense decoder as in-place reads of the
+    stacked weights: ``cache_report()["stream"]`` counts them beside
+    ``compiles``; with Pallas off there are none."""
+    cfg, params = _reduced_model()
+    set_kernel_policy(KernelPolicy(use_pallas=True, interpret=True))
+    reset_dispatch_stats()
+    eng = ServeEngine(cfg, params, max_batch=2, max_len=24, gen_buckets=[4])
+    rep = eng.cache_report()
+    assert rep["compiles"] == 1 and rep["stream"] == 7, rep
+    set_kernel_policy(KernelPolicy(use_pallas=False))
+    reset_dispatch_stats()
+    eng = ServeEngine(cfg, params, max_batch=2, max_len=24, gen_buckets=[4])
+    assert eng.cache_report()["stream"] == 0
+
+
 def test_bucket_padding_avoids_recompiles(clean_dispatch):
     """Prompt-length jitter inside a bucket never compiles a new
     executable, and padded generation matches the exact-shape run."""
