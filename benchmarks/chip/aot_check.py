@@ -88,7 +88,7 @@ def main(argv=None) -> int:
                              if not isinstance(v, (dict, list))))
         progs["reference"] = (
             lambda prm, tok: reference._logits(prm, tok, model_items=items,
-                                               first=tr["prompt_len"] - 1, quant=None),
+                                               first=tr["prompt_len"] - 1, control=False),
             (params, jax.ShapeDtypeStruct((rows, t), jnp.int32, sharding=chip)),
         )
     for name, (fn, fargs) in progs.items():

@@ -3,15 +3,19 @@ the census of the kernels a traced program dispatches.
 
 The census walks the jaxpr of a program as dispatch traced it: each
 ``pallas_call`` is one kernel launch, multiplied by the trip counts of
-the scans around it.  A GEMM launch has two 2-D operands, a flash
-attention launch has the head-major Q (rank 5) and K, V (rank 4) that
-``kernels/flash_attention.py`` hands its ``pallas_call``.
+the scans around it.  A launch is filed by its ``pallas_call`` name under
+the kernel kind of ``launches/<kind>.py``, which reads its dims from the
+operand shapes and counts its operations and bytes.  A launch of a name
+that no file there gives, or of operands its kind does not know, is
+filed as ``other``, with that name and the shapes.
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
+
+from . import spec
 
 __all__ = [
     "Launch",
@@ -33,8 +37,8 @@ __all__ = [
 class Launch:
     """One distinct kernel launch shape and how often a program runs it."""
 
-    kind: str  # "gemm" | "flash"
-    dims: tuple  # gemm: (m, k, n); flash: (b, h, kv, s, hd)
+    kind: str  # a file of launches/ ("gemm", "flash"), or "other"
+    dims: tuple  # the kind's dims; other: (pallas_call name, operand shapes)
     dtype: str
     count: int
 
@@ -52,17 +56,17 @@ def _walk(jaxpr, mult: int, out: collections.Counter) -> None:
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         if name == "pallas_call":
+            kernel = eqn.params["name"]
             avals = [v.aval for v in eqn.invars]
-            ranks = tuple(len(a.shape) for a in avals)
-            dt = str(avals[0].dtype)
-            if ranks == (2, 2):
-                (m, k), (_, n) = avals[0].shape, avals[1].shape
-                out[("gemm", (m, k, n), dt)] += mult
-            elif ranks == (5, 4, 4):
-                b, kv, g, s, hd = avals[0].shape
-                out[("flash", (b, kv * g, kv, s, hd), dt)] += mult
+            shapes = tuple(tuple(a.shape) for a in avals)
+            # the element type of A or Q, past any scalar-prefetched index
+            dt = str(next((a.dtype for a in avals if len(a.shape) >= 2), avals[0].dtype))
+            kind = spec.launch_kinds().get(kernel)
+            dims = spec.load_launch(kind).dims(shapes) if kind else None
+            if dims is None:
+                out[("other", (kernel, shapes), dt)] += mult
             else:
-                out[("other", tuple(a.shape for a in avals), dt)] += mult
+                out[(kind, dims, dt)] += mult
             continue
         inner = mult * int(eqn.params["length"]) if name == "scan" else mult
         for sub in _sub_jaxprs(eqn.params):
@@ -106,23 +110,14 @@ def roofline_s(flops: float, nbytes: float, peak) -> float:
 
 
 def launch_flops(launch: Launch) -> float:
-    if launch.kind == "gemm":
-        return gemm_flops(*launch.dims)
-    if launch.kind == "flash":
-        b, h, _, s, hd = launch.dims
-        return flash_flops(b, h, s, hd)
-    raise ValueError(f"no operation count for a {launch.kind} launch")
+    """Operations of ONE launch of this shape, by its kind's file."""
+    return spec.load_launch(launch.kind).flops(launch.dims)
 
 
 def launch_roofline_s(launch: Launch, peak) -> float:
     """Roofline time of ONE launch of this shape."""
-    if launch.kind == "gemm":
-        nbytes = gemm_bytes(*launch.dims, dtype=launch.dtype)
-    elif launch.kind == "flash":
-        nbytes = flash_bytes(*launch.dims, dtype=launch.dtype)
-    else:
-        raise ValueError(f"no byte count for a {launch.kind} launch")
-    return roofline_s(launch_flops(launch), nbytes, peak)
+    kind = spec.load_launch(launch.kind)
+    return roofline_s(kind.flops(launch.dims), kind.bytes(launch.dims, launch.dtype), peak)
 
 
 # -- whole steps, from the configuration's stated sizes ----------------------

@@ -17,7 +17,7 @@ import os
 
 import numpy as np
 
-from .. import reference, system, weights
+from .. import spec, system, weights
 from ..harness import CompileClock, log, memory_peak_bytes, percentile, wall
 
 __all__ = ["run", "readings"]
@@ -61,8 +61,12 @@ def reference_rows(tokens: int, requests: int) -> int:
 
 
 def _gaps(model, params, prompts, served, control: bool = False) -> np.ndarray:
-    """Reference gaps of the served tokens, a few rows per reference call
-    (one shape: the last chunk is filled up with its first row)."""
+    """Reference gaps of the served tokens, by the reference of the stated
+    family (``families/<family>.py``), or its control's, a few rows per
+    reference call (one shape: the last chunk is filled up with its first
+    row)."""
+    family = spec.load_family(model["family"])
+    gaps = family.control_gaps if control else family.served_gaps
     rows = reference_rows(prompts.shape[1] + served.shape[1] - 1, len(prompts))
     out = []
     for i in range(0, len(prompts), rows):
@@ -71,11 +75,7 @@ def _gaps(model, params, prompts, served, control: bool = False) -> np.ndarray:
         if n < rows:
             p = np.concatenate([p, np.repeat(p[:1], rows - n, 0)])
             s = np.concatenate([s, np.repeat(s[:1], rows - n, 0)])
-        if control:
-            g = reference.control_gaps(model, params, p, s)
-        else:
-            g = reference.served_gaps(model, params, p, s)
-        out.append(np.asarray(g)[:n])
+        out.append(np.asarray(gaps(model, params, p, s))[:n])
     return np.concatenate(out)
 
 

@@ -4,7 +4,7 @@ number, or None where the run holds nothing to read."""
 
 from __future__ import annotations
 
-from . import counts
+from . import counts, spec
 from . import trace as tr
 from .harness import log
 
@@ -32,8 +32,9 @@ def mfu_prefill(run) -> float | None:
     spans = run.get("prefill_s")
     if not spans or run.get("peak") is None:
         return None
-    tr = run["traffic"]
-    flops = counts.prefill_flops(run["model"], tr["batch"], tr["prompt_len"]) * len(spans)
+    tr, model = run["traffic"], run["model"]
+    family = spec.load_family(model["family"])
+    flops = family.prefill_flops(model, tr["batch"], tr["prompt_len"]) * len(spans)
     return 100.0 * flops / sum(spans) / run["peak"].bf16_flops
 
 
@@ -42,9 +43,10 @@ def mfu_decode(run) -> float | None:
     spans = run.get("decode_s")
     if not spans or run.get("peak") is None:
         return None
-    tr = run["traffic"]
+    tr, model = run["traffic"], run["model"]
     steps = max(tr["gen_buckets"])
-    flops = counts.decode_flops(run["model"], tr["batch"], tr["prompt_len"], steps) * len(spans)
+    family = spec.load_family(model["family"])
+    flops = family.decode_flops(model, tr["batch"], tr["prompt_len"], steps) * len(spans)
     return 100.0 * flops / sum(spans) / run["peak"].bf16_flops
 
 
@@ -63,7 +65,9 @@ def kernel_roofline(run, kind: str, phase: str) -> float | None:
 
     A program run is the phase's when it holds a GEMM of a shape that
     only that phase's census has; every launch of the kind in it counts.
-    The launches found must be the census's, call for call."""
+    The launches found must be the census's, call for call.  A ``kind``
+    with no file in ``launches/`` is an error that names the file."""
+    spec.load_launch(kind)
     trace, census, traced = run.get("trace"), run.get("census"), run.get("traced")
     if trace is None or not census or traced is None or run.get("peak") is None:
         return None
@@ -85,5 +89,6 @@ def kernel_roofline(run, kind: str, phase: str) -> float | None:
     if len(events) != want:
         log(f"{kind} in {phase}: {len(events)} launches in the trace, census says {want}")
         return None
+    log(f"{kind} in {phase}: {len(events)} launches in the trace, as the census says")
     device_s = sum(e.dur_ns for e in events) / 1e9
     return 100.0 * census_roofline_s(launches, run["peak"]) * calls / device_s

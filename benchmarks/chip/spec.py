@@ -5,21 +5,29 @@
     traffic/<traffic>.json   a traffic mix: the driver and its parameters
     limits/<cell>.json       the correctness limits of one cell
     metrics/<metric>.py      the reader of one per-layer metric
+    families/<family>.py     a model family's reference, control and
+                             step FLOPs, and its stated keys
+    launches/<kind>.py       a kernel kind: its ``pallas_call`` name,
+                             census dims, operations and bytes
 
-A later cell, configuration, mix or metric is a new file; nothing here
-names one of them.
+A later cell, configuration, mix, metric, family or kernel kind is a new
+file; nothing here names one of them.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import pathlib
 import re
 from typing import Optional
 
-__all__ = ["HERE", "ROOT", "Cell", "Benchmark", "load", "load_metric_reader"]
+__all__ = [
+    "HERE", "ROOT", "Cell", "Benchmark", "load", "load_metric_reader", "load_family",
+    "load_launch", "launch_kinds",
+]
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -88,12 +96,43 @@ def load(path: Optional[pathlib.Path] = None) -> Benchmark:
     return Benchmark(_read_json(path or ROOT / "BENCHMARK.json"))
 
 
-def load_metric_reader(name: str):
-    """The ``read(run)`` function of ``metrics/<name>.py``."""
-    path = HERE / "metrics" / f"{_check_name('metric', name)}.py"
-    mod_spec = importlib.util.spec_from_file_location(f"_metric_{name}", path)
-    if mod_spec is None or mod_spec.loader is None:
-        raise ImportError(f"no reader for metric {name!r} at {path}")
+def _load_module(folder: str, kind: str, name: str, needs: str):
+    """The module ``<folder>/<name>.py``, loaded by path; a missing one is
+    an error that names the file to add and what it must define."""
+    path = HERE / folder / f"{_check_name(kind, name)}.py"
+    if not path.is_file():
+        raise LookupError(f"no {kind} {name!r}: add {path.relative_to(ROOT)} with {needs}")
+    mod_spec = importlib.util.spec_from_file_location(f"_{folder}_{name}", path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_metric_reader(name: str):
+    """The ``read(run)`` function of ``metrics/<name>.py``."""
+    return _load_module("metrics", "metric", name, "read(run)").read
+
+
+@functools.cache
+def load_family(name: str):
+    """``families/<name>.py``: the family's ``served_gaps`` and
+    ``control_gaps``, ``prefill_flops`` and ``decode_flops``, and
+    ``FIELDS``, its stated keys beyond the shared ones."""
+    return _load_module(
+        "families", "family", name,
+        "served_gaps, control_gaps, prefill_flops, decode_flops and FIELDS")
+
+
+@functools.cache
+def load_launch(kind: str):
+    """``launches/<kind>.py``: the kernel's ``pallas_call`` ``NAME``, and
+    ``dims(shapes)``, ``flops(dims)`` and ``bytes(dims, dtype)``."""
+    return _load_module("launches", "kernel kind", kind,
+                        "NAME, dims(shapes), flops(dims) and bytes(dims, dtype)")
+
+
+@functools.cache
+def launch_kinds() -> dict:
+    """``pallas_call`` name -> kernel kind, for every file in ``launches/``."""
+    kinds = sorted(p.stem for p in (HERE / "launches").glob("*.py"))
+    return {load_launch(k).NAME: k for k in kinds}

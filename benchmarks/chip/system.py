@@ -10,11 +10,12 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from . import counts
+from . import counts, spec
 
-__all__ = ["arch_config", "stated_model", "prefill_census", "decode_census"]
+__all__ = ["fields", "arch_config", "stated_model", "prefill_census", "decode_census"]
 
-#: stated key -> ArchConfig field
+#: stated key -> ArchConfig field, for every family; a family's file in
+#: ``families/`` adds its own (``FIELDS``)
 _FIELDS = {
     "family": "family", "n_layers": "n_layers", "d_model": "d_model",
     "n_heads": "n_heads", "n_kv_heads": "n_kv_heads", "head_dim": "head_dim",
@@ -23,25 +24,39 @@ _FIELDS = {
     "tie_embeddings": "tie_embeddings", "qkv_bias": "qkv_bias",
     "dtype": "param_dtype",
 }
+#: stated only where a family departs from them: rotary positions and no
+#: softcap, which the program must run where the file does not state them
+_DEFAULTS = {"pos_embed": "rope", "attn_softcap": 0.0}
+
+
+def fields(family: str) -> dict:
+    """Stated key -> ArchConfig field that every file of ``family`` states."""
+    return _FIELDS | spec.load_family(family).FIELDS
 
 
 def arch_config(config: dict, rehearsal: bool = False):
     """The program's ArchConfig for a configuration file, checked
-    against the sizes the file states.  A rehearsal on the CPU runs the
-    registry's tiny ``reduced()`` variant of it, in bf16."""
+    against the sizes the file states: every stated key against its
+    field, the shared ones and the family's.  A stated key that no field
+    checks is refused.  A rehearsal on the CPU runs the registry's tiny
+    ``reduced()`` variant of it, in bf16."""
     from repro.configs.registry import get_arch
 
     cfg = dataclasses.replace(get_arch(config["arch"]), **config["overrides"])
     if rehearsal:
         return cfg.reduced(param_dtype="bfloat16", compute_dtype="bfloat16")
-    bad = {
-        k: (v, getattr(cfg, f)) for k, f in _FIELDS.items()
-        if (v := config["model"][k]) != getattr(cfg, f)
-    }
-    if cfg.compute_dtype != config["model"]["dtype"]:
-        bad["compute_dtype"] = (config["model"]["dtype"], cfg.compute_dtype)
-    if cfg.pos_embed != "rope" or cfg.attn_softcap:
-        bad["positions"] = ("rope, no softcap", (cfg.pos_embed, cfg.attn_softcap))
+    model = config["model"]
+    want = fields(model["family"])
+    unchecked = sorted(set(model) - set(want) - set(_DEFAULTS))
+    missing = sorted(set(want) - set(model))
+    if unchecked or missing:
+        raise ValueError(
+            f"{config['arch']}: stated keys with no ArchConfig field {unchecked} (a family "
+            f"maps its own in families/{model['family']}.py FIELDS), keys not stated {missing}")
+    stated = {k: (model[k], f) for k, f in want.items()}
+    stated |= {k: (model.get(k, v), k) for k, v in _DEFAULTS.items()}
+    stated["compute_dtype"] = (model["dtype"], "compute_dtype")
+    bad = {k: (v, getattr(cfg, f)) for k, (v, f) in stated.items() if v != getattr(cfg, f)}
     if bad:
         raise ValueError(f"program config {cfg.name} differs from the stated sizes: {bad}")
     return cfg
@@ -49,13 +64,11 @@ def arch_config(config: dict, rehearsal: bool = False):
 
 def stated_model(config: dict, cfg, rehearsal: bool = False) -> dict:
     """The sizes the reference and the counts use: the file's, or the
-    rehearsal config's own."""
+    rehearsal config's own under the same keys."""
     if not rehearsal:
         return dict(config["model"])
-    return {k: getattr(cfg, f) for k, f in _FIELDS.items()} | {
-        "mlp": cfg.mlp_kind, "dtype": cfg.param_dtype,
-        "head_dim": cfg.resolved_head_dim,
-    }
+    return {k: getattr(cfg, f) for k, f in fields(cfg.family).items()} | {
+        "head_dim": cfg.resolved_head_dim}
 
 
 def abstract_params(cfg):

@@ -72,3 +72,77 @@ def test_census_of_a_reduced_prefill():
     assert {dataclasses.replace(c, count=0) for c in dec} >= {
         counts.Launch("gemm", (2, d, cfg.padded_vocab), "bfloat16", 0)}
     assert all(c.count % 3 == 0 for c in dec)
+
+
+def test_census_of_a_reduced_decode_step():
+    """Every projection of every layer, read in place from the stacked
+    weights with a scalar-prefetched layer index, is a ``gemm`` launch of
+    ``(m, k, n)``: as many a step as dispatch streamed at trace time
+    times the layers, and nothing is left under ``other``."""
+    from repro.configs.registry import get_arch
+    from repro.kernels import ops
+
+    from benchmarks.chip import system
+
+    cfg = get_arch("yi-6b").reduced(param_dtype="bfloat16", compute_dtype="bfloat16")
+    ops.set_kernel_policy(ops.KernelPolicy(use_pallas=True))
+    ops.reset_dispatch_stats()
+    try:
+        got = {(c.kind, c.dims): c.count for c in system.decode_census(cfg, 2, 520, 1)}
+        stream = ops.dispatch_stats()["gemm"]["stream"]
+        prefill = {(c.kind, c.dims) for c in system.prefill_census(cfg, 2, 512, 520)}
+    finally:
+        ops.set_kernel_policy(ops.KernelPolicy())
+    d, hd, h, kv, f, m, layers = 64, 16, 4, 2, 128, 2, cfg.n_layers
+    head = ("gemm", (m, d, cfg.padded_vocab))
+    assert got == {
+        ("gemm", (m, d, h * hd)): 2 * layers,  # wq and wo
+        ("gemm", (m, d, kv * hd)): 2 * layers,  # wk, wv
+        ("gemm", (m, d, f)): 2 * layers,  # wi, wg
+        ("gemm", (m, f, d)): layers,
+        head: 1,
+    }
+    assert stream == 7
+    assert sum(n for k, n in got.items() if k != head) == stream * layers
+    # the head alone is in both: which program run is the prefill's, by the
+    # GEMMs only its census has, does not change with the decode launches
+    assert set(got) & prefill == {head}
+
+
+def test_unknown_kernel_is_other_by_its_name():
+    """A ``pallas_call`` whose name no file in ``launches/`` gives is
+    filed under ``other``, keyed by that name and its operand shapes."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2
+
+    fn = pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+                        name="mystery_kernel", interpret=True)
+    got = counts.census(jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((8, 128), jnp.float32)))
+    assert got == [counts.Launch("other", ("mystery_kernel", ((8, 128),)), "float32", 1)]
+
+
+def test_unknown_kernel_kind_names_the_file_to_add():
+    from benchmarks.chip.readers import kernel_roofline
+
+    with pytest.raises(LookupError, match="benchmarks/chip/launches/grouped_gemm.py"):
+        kernel_roofline({}, "grouped_gemm", "decode")
+    with pytest.raises(LookupError, match="benchmarks/chip/launches/grouped_gemm.py"):
+        counts.launch_roofline_s(counts.Launch("grouped_gemm", (4, 8, 16), "bfloat16", 1), V5E)
+
+
+def test_stacked_gemm_counts_one_layer_of_the_stack():
+    """The layer-indexed launch reads one K x N slab of its (L, K, N)
+    stack: the same operations and least bytes as a 2-D GEMM."""
+    from benchmarks.chip import spec
+
+    gemm = spec.load_launch("gemm")
+    assert gemm.dims([(1,), (32, 4096), (32, 4096, 11008)]) == (32, 4096, 11008)
+    assert gemm.dims([(32, 4096), (4096, 11008)]) == (32, 4096, 11008)
+    assert gemm.dims([(32, 4096)]) is None
+    launch = counts.Launch("gemm", (32, 4096, 11008), "bfloat16", 1)
+    assert counts.launch_roofline_s(launch, V5E) == pytest.approx(
+        (32 * 4096 + 4096 * 11008 + 32 * 11008) * 2 / 819e9)

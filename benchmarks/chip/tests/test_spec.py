@@ -50,6 +50,23 @@ def test_every_file_is_named():
         m["name"] for m in RAW["per_layer"]}
     assert {(spec.ROOT / c["file"]).resolve() for c in RAW["configs"]} == set(
         (spec.HERE / "configs").glob("*.json"))
+    stated = {json.loads((spec.ROOT / c["file"]).read_text())["model"]["family"]
+              for c in RAW["configs"]}
+    assert stated <= {p.stem for p in (spec.HERE / "families").glob("*.py")} <= stated | {"dense"}
+    kernels = "\n".join(p.read_text() for p in (spec.ROOT / "src/repro/kernels").glob("*.py"))
+    for kind in (spec.HERE / "launches").glob("*.py"):
+        assert f'name="{spec.load_launch(kind.stem).NAME}"' in kernels, kind.name
+
+
+@pytest.mark.parametrize("path", sorted((spec.HERE / "families").glob("*.py")), ids=lambda p: p.stem)
+def test_family_gives_the_contract(path):
+    """Each family gives its reference, control, step FLOPs and stated
+    keys, and, like ``reference.py``, imports nothing of the program."""
+    fam = spec.load_family(path.stem)
+    for name in ("served_gaps", "control_gaps", "prefill_flops", "decode_flops"):
+        assert callable(getattr(fam, name)), name
+    assert all(isinstance(f, str) for f in fam.FIELDS.values())
+    assert "repro" not in path.read_text()
 
 
 def test_unknown_device_is_an_error():

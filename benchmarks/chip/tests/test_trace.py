@@ -18,6 +18,29 @@ def test_union_of_intervals():
     assert trace.union_ns([(20, 30), (0, 10), (2, 3)]) == 20  # nested and unsorted
 
 
+@pytest.mark.parametrize("kind,hlo,dims", [
+    ("gemm", "%gemm_pallas.66 = bf16[32,11008]{1,0} custom-call(s32[1]{0} %bitcast.5, "
+     "bf16[32,4096]{1,0} %fusion.3, bf16[32,4096,11008]{2,1,0} %param.7), "
+     'custom_call_target="tpu_custom_call"', (32, 4096, 11008)),
+    ("gemm", "%gemm_pallas.50 = bf16[8192,4096]{1,0} custom-call(bf16[8192,11008]{1,0} %a, "
+     'bf16[11008,4096]{1,0} %b), custom_call_target="tpu_custom_call"', (8192, 11008, 4096)),
+    ("flash", "%flash_attention.6 = bf16[2,4,8,4096,128]{4,3,2,1,0} custom-call("
+     "bf16[2,4,8,4096,128]{4,3,2,1,0} %q, bf16[2,4,4096,128]{3,2,1,0} %k, "
+     'bf16[2,4,4096,128]{3,2,1,0} %v), custom_call_target="tpu_custom_call"',
+     (2, 32, 4, 4096, 128)),
+])
+def test_launch_dims_of_an_hlo_line(kind, hlo, dims):
+    """A kernel event is found by its ``pallas_call`` name and read as the
+    census reads its jaxpr: the layer-indexed GEMM's scalar-prefetched
+    index comes first, as in the jaxpr's operands."""
+    event = trace.Event(hlo, 0.0, 1.0)
+    assert trace.launch_dims(kind, event) == dims
+    t = trace.Trace(device={"/device:TPU:0": {"XLA Ops": [event]}}, host={})
+    assert trace.kernel_events(t, kind) == [event]
+    other = "flash" if kind == "gemm" else "gemm"
+    assert trace.kernel_events(t, other) == []
+
+
 def test_busy_and_idle_gaps_of_synthetic_trace():
     ev = lambda n, a, b: trace.Event(n, a, b - a)  # noqa: E731
     t = trace.Trace(
@@ -77,3 +100,48 @@ def test_tune_gemm_roofline_reads_the_recorded_kernels(small):
     assert read(dict(run, rounds=meta["calls"] + 1)) is None
     other = Launch("gemm", (128, 512, 256), "bfloat16", 1)
     assert read(dict(run, census={"tune": [other]})) is None
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a v5e described, not attached; described inside the
+    fixture, never at import (one process at a time may load the TPU
+    library)."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_layer_indexed_gemm_hlo_reads_as_its_census(one_chip):
+    """The layer-indexed GEMM as the TPU compiler emits it, at yi-6b's
+    decode shape: its instruction is found by the kernel's name, and its
+    operands (the index, A, the stack) read as the census's ``(m, k, n)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.gemm import default_config, gemm_pallas
+
+    m, k, n = 32, 4096, 11008
+    cfg = default_config(m, k, n)
+    args = [jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((2, k, n), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)]
+    from jaxlib._jax import HloPrintOptions
+
+    compiled = jax.jit(lambda a, w, li: gemm_pallas(a, w, cfg, layer=li)).lower(*args).compile()
+    opts = HloPrintOptions()  # as the profiler names an op: operand shapes printed
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    calls = [line.strip().removeprefix("ROOT ") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    assert trace._KERNELS["gemm"].match(calls[0]), calls[0]
+    assert trace.launch_dims("gemm", trace.Event(calls[0], 0.0, 1.0)) == (m, k, n), calls[0]

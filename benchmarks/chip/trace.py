@@ -17,6 +17,8 @@ import os
 import re
 from typing import Iterable, Optional
 
+from . import spec
+
 __all__ = ["Event", "Trace", "load", "union_ns", "find_xplane"]
 
 _DEVICE = re.compile(r"^/device:(TPU|GPU):\d+$")
@@ -198,17 +200,24 @@ def describe(path: str, top: int = 40) -> dict:
 
 # -- kernels ------------------------------------------------------------------
 
-#: the HLO instruction of each Pallas kernel is named after the program's
-#: jitted kernel function (``%gemm_pallas.3 = bf16[...] custom-call(...)``)
+#: the HLO instruction of each Pallas kernel is named after its
+#: ``pallas_call`` (``%gemm_pallas.3 = bf16[...] custom-call(...)``), whose
+#: name each kernel kind's file in ``launches/`` gives
 _KERNELS = {
-    "gemm": re.compile(r"^%gemm_pallas(?:\.\d+)? = .*custom-call\("),
-    "flash": re.compile(r"^%flash_attention(?:\.\d+)? = .*custom-call\("),
+    kind: re.compile(rf"^%{re.escape(name)}(?:\.\d+)? = .*custom-call\(")
+    for name, kind in spec.launch_kinds().items()
 }
 _OPERAND = re.compile(r"\b[a-z]+\d*\[([\d,]*)\]")
 #: ops whose events enclose other ops' events
 _CONTAINER = re.compile(r"^\S+ (while|conditional|call)\(")
 #: where an instruction's operand list ends and its attributes begin
 _ARGS_END = re.compile(r"\),\s*[a-z_]+=")
+
+
+def _kernel(kind: str):
+    if kind not in _KERNELS:
+        spec.load_launch(kind)  # raises, naming the file to add
+    return _KERNELS[kind]
 
 
 def operand_shapes(event: Event) -> list:
@@ -218,20 +227,15 @@ def operand_shapes(event: Event) -> list:
 
 
 def launch_dims(kind: str, event: Event) -> Optional[tuple]:
-    """The census dims of one kernel event: GEMM ``(m, k, n)``, flash
-    ``(b, heads, kv_heads, s, hd)``."""
-    shapes = operand_shapes(event)
-    if kind == "gemm" and len(shapes) >= 2 and len(shapes[0]) == 2:
-        (m, k), (_, n) = shapes[0], shapes[1]
-        return (m, k, n)
-    if kind == "flash" and shapes and len(shapes[0]) == 5:
-        b, kv, g, s, hd = shapes[0]
-        return (b, kv * g, kv, s, hd)
-    return None
+    """The census dims of one kernel event, read from its operands by the
+    kind's file as the census reads them from the jaxpr's: a GEMM's
+    ``(m, k, n)``, also with a scalar-prefetched layer index first;
+    flash's ``(b, heads, kv_heads, s, hd)``."""
+    return spec.load_launch(kind).dims(operand_shapes(event))
 
 
 def kernel_events(tr: Trace, kind: str) -> list:
-    pat = _KERNELS[kind]
+    pat = _kernel(kind)
     return [e for evs in tr.ops().values() for e in evs if pat.match(e.name)]
 
 
@@ -246,7 +250,7 @@ def kernel_seconds(tr: Trace, kind: str) -> float:
 def module_kernels(tr: Trace, kind: str) -> list:
     """``(module event, [its kernel events])`` for every executable run on
     every device: a kernel belongs to the run whose interval holds its start."""
-    pat = _KERNELS[kind]
+    pat = _kernel(kind)
     out = []
     for plane, lines in tr.device.items():
         mods = lines.get("XLA Modules", [])
